@@ -1,9 +1,12 @@
-"""Device-scaling benchmark for the sharded query execution engine.
+"""Device-scaling benchmark for the sharded query execution engine on
+simulated CPU devices — a standalone CPU tool, not part of
+``benchmarks/run.py`` (that process holds the accelerator, and a child
+that needs it would fail or hang).
 
 The ``--xla_force_host_platform_device_count`` flag must reach XLA before
-jax initialises, so this module is a standalone entrypoint that sets the
-flag and only then imports the benchmark stack; ``benchmarks/run.py``
-launches it as a subprocess.
+jax initialises, so this module sets the flag (and pins JAX to the CPU)
+and only then imports the benchmark stack.  Its numbers are CPU numbers
+and say nothing about a chip.
 
     PYTHONPATH=src python -m benchmarks.engine_bench --devices 8 \
         --scale small --out experiments/bench/engine_scaling.json
@@ -30,6 +33,7 @@ def main() -> None:
     os.environ["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count={args.devices}"
     ).strip()
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     from benchmarks import ir_bench         # imports jax with the flag set
 

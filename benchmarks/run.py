@@ -6,7 +6,9 @@
   KERN  kernel micro-benches                            [kernel_bench]
 
 Prints ``name,us_per_call,derived`` CSV rows per the harness contract, plus
-the full tables; writes JSON artifacts under experiments/bench/.
+the full tables; writes JSON artifacts under experiments/bench/.  Runs in
+one process, which holds the accelerator; the simulated-device engine
+scaling bench is a separate CPU tool (``benchmarks/engine_bench.py``).
 
   PYTHONPATH=src python -m benchmarks.run [--scale robust|small] [--skip-ir]
 """
@@ -14,31 +16,12 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
 from pathlib import Path
 
 from benchmarks import ir_bench, kernel_bench, roofline, serve_bench
+from repro.launch.cache import use_compile_cache
 
 OUT = Path("experiments/bench")
-
-
-def run_engine_bench(scale: str, repeats: int, devices: int = 8) -> dict | None:
-    """Device-sharded engine scaling, in a subprocess: the simulated-device
-    XLA flag must be set before jax initialises, which this (already
-    jax-initialised) process can no longer do."""
-    out = OUT / "engine_scaling.json"
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, "-m", "benchmarks.engine_bench",
-           "--devices", str(devices), "--scale", scale,
-           "--repeats", str(repeats), "--out", str(out)]
-    proc = subprocess.run(cmd, env=env)
-    if proc.returncode != 0 or not out.exists():
-        print("# engine scaling bench failed; see output above")
-        return None
-    return json.loads(out.read_text())
 
 
 def main() -> None:
@@ -47,6 +30,7 @@ def main() -> None:
     ap.add_argument("--skip-ir", action="store_true")
     ap.add_argument("--repeats", type=int, default=3)
     args = ap.parse_args()
+    use_compile_cache()
     OUT.mkdir(parents=True, exist_ok=True)
     # clear stale section files: summary.json is merged from OUT/*.json, so
     # a leftover section from a previous run would mask exactly the
@@ -247,32 +231,6 @@ def main() -> None:
                 "derived": (f"cross_hits={tt['cross_pipeline_hits']},"
                             f"served={tt['served']},"
                             f"recompiles={tt['recompiles_since_warmup']}")})
-
-    # --- ENGINE: device-sharded query throughput -------------------------
-    if not args.skip_ir:
-        eng = run_engine_bench(args.scale, args.repeats)
-        if eng is not None:
-            print("\n== Engine: device-sharded scaling ==")
-            print(f"(host cpus: {eng['host_cpus']}; device speedup "
-                  f"saturates at host cores)")
-            n_ladder = len(eng["bucket_ladder"])
-            for name, wl in eng["workloads"].items():
-                print(f"[{name}] sequential: {wl['sequential_qps']} q/s")
-                csv_rows.append({
-                    "name": f"engine_{name}_sequential",
-                    "us_per_call": round(1e6 / max(wl["sequential_qps"],
-                                                   1e-9), 2),
-                    "derived": ""})
-                for row in wl["rows"]:
-                    print(f"  {row}")
-                    csv_rows.append({
-                        "name": f"engine_{name}_{row['devices']}dev",
-                        "us_per_call": round(1e6 / max(row["qps"], 1e-9), 2),
-                        "derived": (f"qps={row['qps']},"
-                                    f"speedup={row['speedup_vs_sequential']}x,"
-                                    f"recompiles="
-                                    f"{row['max_recompiles_per_stage']}"
-                                    f"<=ladder={n_ladder}")})
 
     # --- ROOF ---------------------------------------------------------------
     recs = roofline.load_records()

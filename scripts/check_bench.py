@@ -1,10 +1,9 @@
 """Fail loudly when the bench run silently dropped a section.
 
 The bench-smoke CI job uploads ``summary.json`` as the per-push trajectory
-artifact; a section that vanishes (e.g. the engine-scaling subprocess died,
-or the fusion bench was skipped) used to pass silently and poison the
-trajectory.  This gate requires the sections the trajectory tracks to be
-present AND non-empty.
+artifact; a section that vanishes (e.g. the fusion bench was skipped) used
+to pass silently and poison the trajectory.  This gate requires the
+sections the trajectory tracks to be present AND non-empty.
 
     python scripts/check_bench.py [experiments/bench/summary.json]
 """
@@ -13,8 +12,7 @@ from __future__ import annotations
 import json
 import sys
 
-REQUIRED = ("engine_scaling", "fusion", "rq1", "rq2", "dense", "serve",
-            "autotune", "obs")
+REQUIRED = ("fusion", "rq1", "rq2", "dense", "serve", "autotune", "obs")
 
 #: every serve workload must report at least this many offered-load levels
 #: (p50/p95/p99 batched vs naive at light/mid/sat/overload)

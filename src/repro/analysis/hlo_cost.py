@@ -33,6 +33,9 @@ _DTYPE_BYTES = {
 }
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+#: a shape with its layout; a TPU layout tagged ``S(1)`` places the buffer
+#: in on-chip VMEM (memory-space assignment), so it is no HBM traffic
+_LAID_OUT_RE = re.compile(r"(\w+)\[([\d,]*)\](\{[^}]*\})?")
 _INSTR_HEAD_RE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$")
 _OP_RE = re.compile(r"\s*([\w\-]+)\(")
 _COMP_HDR_RE = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s*\((.*?)\)\s*->")
@@ -45,7 +48,11 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 #: zero-traffic bookkeeping ops
 _FREE = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
          "after-all", "partition-id", "replica-id", "iota", "copy-start",
-         "copy-done", "domain", "opt-barrier"}
+         "copy-done", "domain", "opt-barrier", "slice-done"}
+
+#: custom calls that move no data (TPU layout bookkeeping)
+_FREE_CUSTOM_CALLS = ("ConcatBitcast", "AssumeGatherIndicesInBound")
+_TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
 
 
 def shape_elems(type_str: str) -> int:
@@ -62,10 +69,12 @@ def shape_elems(type_str: str) -> int:
 
 
 def shape_bytes(type_str: str) -> int:
+    """HBM bytes of a (tuple) type: buffers laid out in VMEM (``S(1)``)
+    count zero."""
     total = 0
-    for m in _SHAPE_RE.finditer(type_str):
+    for m in _LAID_OUT_RE.finditer(type_str):
         dt = m.group(1)
-        if dt not in _DTYPE_BYTES:
+        if dt not in _DTYPE_BYTES or "S(1)" in (m.group(3) or ""):
             continue
         k = 1
         for d in m.group(2).split(","):
@@ -223,6 +232,15 @@ def _instr_bytes(inst: Instr, shapes: dict[str, str]) -> float:
         return rb + 3.0 * upd
     if op == "dynamic-slice":
         return 2.0 * rb
+    if op == "slice-start":
+        # async slice: the result tuple aliases the whole operand; only the
+        # slice itself is read and written
+        ob = sum(shape_bytes(shapes.get(o, "")) for o in inst.operands)
+        return 2.0 * max(rb - ob, 0)
+    if op == "custom-call":
+        mt = _TARGET_RE.search(inst.rest)
+        if mt and mt.group(1) in _FREE_CUSTOM_CALLS:
+            return 0.0
     if op == "dynamic-update-slice":
         upd = shape_bytes(shapes.get(inst.operands[1], "")) if len(inst.operands) > 1 else 0
         return 2.0 * upd
@@ -305,6 +323,8 @@ class CostModel:
                 total.flops += 2.0 * shape_elems(inst.rtype)
                 total.bytes += _instr_bytes(inst, comp.shapes)
                 continue
+            if op == "custom-call" and _instr_bytes(inst, comp.shapes) == 0:
+                continue                          # free bookkeeping call
             # elementwise / reduce / misc: 1 flop per output element
             total.flops += float(shape_elems(inst.rtype))
             total.bytes += _instr_bytes(inst, comp.shapes)
@@ -328,12 +348,42 @@ def analyze(hlo_text: str) -> dict[str, Any]:
 # callable estimation — the pipeline compiler's cost gate (core/passes.py)
 # ---------------------------------------------------------------------------
 
-#: nominal per-chip peaks for the roofline time proxy — the *uncalibrated*
-#: defaults (TPU-class chip).  A ratio gate only needs the flops:bytes
-#: weighting to be plausible; a calibrated BackendDescriptor replaces both
-#: constants with per-host fits from measured bench ratios (``fit_peaks``).
-PEAK_FLOPS_PER_S = 1.0e14
-PEAK_BYTES_PER_S = 1.0e12
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Per-chip roofline peaks of one device kind, with their source."""
+    flops_per_s: float
+    bytes_per_s: float
+    source: str
+    ici_bytes_per_s: float | None = None     # per link
+
+
+#: roofline peaks keyed by ``jax.Device.device_kind``.  A kind that is not
+#: listed is an error, never a default: pricing a chip with another chip's
+#: peaks would silently skew every gate decision.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        197e12, 819e9,
+        "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, 16 GB "
+        "HBM at 819 GB/s; 1,600 Gbit/s ICI over 4 links",
+        ici_bytes_per_s=50e9),
+    "cpu": DevicePeaks(
+        1.0e14, 1.0e12,
+        "uncalibrated proxy, not a property of any CPU: nominal TPU-class "
+        "constants kept so CPU gate decisions only order candidates by a "
+        "plausible flops:bytes weighting (calibrate with fit_peaks)"),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """The :data:`DEVICE_PEAKS` row for ``device_kind``; raises for a kind
+    with no row."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device kind {device_kind!r}; add a row "
+            f"with its source to DEVICE_PEAKS (have "
+            f"{sorted(DEVICE_PEAKS)})") from None
 
 
 def host_fingerprint() -> str:
@@ -345,21 +395,20 @@ def host_fingerprint() -> str:
     return hashlib.sha256(raw.encode()).hexdigest()[:16]
 
 
-def estimate_callable(fn, *args, peaks: tuple[float, float] | None = None
+def estimate_callable(fn, *args, peaks: tuple[float, float]
                       ) -> dict[str, Any]:
     """Lower ``fn(*args)`` (args may be ``jax.ShapeDtypeStruct`` pytrees) to
     post-optimisation HLO and run the trip-count-aware cost model over it.
 
     Adds ``time_proxy_s`` — flops/peak + bytes/peak, an additive roofline
     proxy: comparing two candidates' proxies orders them by modelled cost
-    even when one resource dominates.  ``peaks`` overrides the nominal
-    ``(PEAK_FLOPS_PER_S, PEAK_BYTES_PER_S)`` — calibrated descriptors pass
-    their fitted per-host constants.  Used by the fusion pass's cost gate;
-    callers should cache per content key (compilation is the expensive part).
+    even when one resource dominates.  ``peaks`` is ``(flop/s, byte/s)``:
+    a :data:`DEVICE_PEAKS` row, or a calibrated descriptor's fitted
+    constants.  Used by the fusion pass's cost gate; callers should cache
+    per content key (compilation is the expensive part).
     """
     import jax
-    pf, pb = peaks if peaks is not None else (PEAK_FLOPS_PER_S,
-                                              PEAK_BYTES_PER_S)
+    pf, pb = peaks
     text = jax.jit(fn).lower(*args).compile().as_text()
     out = analyze(text)
     out["time_proxy_s"] = (out["flops_per_chip"] / pf

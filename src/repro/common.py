@@ -142,6 +142,11 @@ class LRU:
         with self._lock:
             return list(self._d.values())
 
+    def items(self) -> list:
+        """Snapshot copy of the (key, value) pairs, like :meth:`values`."""
+        with self._lock:
+            return list(self._d.items())
+
     def clear(self) -> None:
         with self._lock:
             self._d.clear()

@@ -10,9 +10,11 @@ backend *as data*:
 * kernel limits               (the native-k ceilings of the Pallas kernels,
                                so "will this K hit the kernel fast path" is
                                a descriptor lookup, not an import),
-* per-host peak constants     (the roofline peaks the HLO cost gate prices
-                               with — calibratable from measured bench
-                               ratios via ``analysis.hlo_cost.fit_peaks``),
+* per-device peak constants   (the roofline peaks the HLO cost gate prices
+                               with, from the device kind's row of
+                               ``analysis.hlo_cost.DEVICE_PEAKS`` —
+                               calibratable from measured bench ratios via
+                               ``analysis.hlo_cost.fit_peaks``),
 * a tuning-profile handle     (persisted gate decisions keyed by
                                ``(backend digest, op key, bucket)``), and
 * autotune policy             (opt-in probe measurement of gate candidates
@@ -228,12 +230,13 @@ class BackendDescriptor:
     def default(cls, capabilities: frozenset | None = None,
                 **overrides) -> "BackendDescriptor":
         """Descriptor for the in-process JAX backend: full (or given)
-        capability set, kernel limits read off the kernel packages, nominal
-        roofline peaks from ``analysis.hlo_cost``, this host's
-        fingerprint."""
-        from repro.analysis.hlo_cost import (PEAK_BYTES_PER_S,
-                                             PEAK_FLOPS_PER_S,
-                                             host_fingerprint)
+        capability set, kernel limits read off the kernel packages, the
+        roofline peaks of the default device's kind from
+        ``analysis.hlo_cost.DEVICE_PEAKS`` (an unlisted kind raises), this
+        host's fingerprint."""
+        import jax
+
+        from repro.analysis.hlo_cost import device_peaks, host_fingerprint
         from repro.kernels.dense_scoring.ops import MAX_KERNEL_K as DENSE_K
         from repro.kernels.pq_scoring.ops import MAX_KERNEL_K as PQ_K
         from repro.kernels.topk.ops import MAX_KERNEL_K as TOPK_K
@@ -243,11 +246,13 @@ class BackendDescriptor:
             kernel_limits=(("topk", TOPK_K), ("fat", None),
                            ("dense_topk", DENSE_K), ("dense_rerank", DENSE_K),
                            ("pq_topk", PQ_K)),
-            peak_flops_per_s=PEAK_FLOPS_PER_S,
-            peak_bytes_per_s=PEAK_BYTES_PER_S,
             host=host_fingerprint(),
         )
         kw.update(overrides)
+        if "peak_flops_per_s" not in kw or "peak_bytes_per_s" not in kw:
+            peaks = device_peaks(jax.devices()[0].device_kind)
+            kw.setdefault("peak_flops_per_s", peaks.flops_per_s)
+            kw.setdefault("peak_bytes_per_s", peaks.bytes_per_s)
         return cls(**kw)
 
     def with_profile(self, profile: TuningProfile | None, *,

@@ -8,7 +8,8 @@ users"), built from three mechanisms:
   across all local devices through a 1-D ``("data",)`` mesh
   (:func:`repro.launch.mesh.make_query_mesh`, the serving counterpart of
   the training meshes).  Per-query stage functions are embarrassingly
-  parallel along the batch, so GSPMD partitions them with zero collectives.
+  parallel along the batch, so each device runs them on its own rows under
+  ``shard_map`` (:func:`data_parallel`) with zero collectives.
 
 * **Bucket ladder** — query batches are padded up to a small fixed ladder
   of chunk sizes and executed through a persistent jit cache keyed by
@@ -36,6 +37,7 @@ import weakref
 from typing import Any, Callable, Sequence
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -67,6 +69,56 @@ def default_bucket_ladder(n_devices: int, *, base: int = 8,
         if b not in ladder:
             ladder.append(b)
     return tuple(sorted(ladder))
+
+
+class data_parallel:
+    """``jit(vmap(fn))`` with the query axis split over the mesh's ``data``
+    axis by ``shard_map``: each device runs the per-query function on its
+    own rows.  Explicit SPMD rather than GSPMD partitioning, because a
+    Pallas (Mosaic) kernel inside ``fn`` cannot be partitioned
+    automatically.
+
+    The arrays ``fn`` closes over (index, embeddings) enter the program as
+    replicated arguments, never as constants: embedded, each compiled rung
+    would carry its own copy of the index in its executable — gigabytes at
+    Robust scale, slow to compile and resident on the device once per
+    program.  ``place`` puts each such array on the mesh (default: as is).
+    Callable like the jitted function; :meth:`lower` lowers it."""
+
+    def __init__(self, fn, mesh, place: Callable | None = None):
+        self.fn, self.mesh = fn, mesh
+        self.place = place if place is not None else (lambda x: x)
+        self._programs: dict = {}
+
+    def _program(self, args):
+        sig = tuple((tuple(a.shape[1:]), jnp.dtype(a.dtype)) for a in args)
+        prog = self._programs.get(sig)
+        if prog is None:
+            closed, out_shape = jax.make_jaxpr(self.fn, return_shape=True)(
+                *[jax.ShapeDtypeStruct(s, d) for s, d in sig])
+            jaxpr, out_tree = closed.jaxpr, jax.tree.structure(out_shape)
+
+            def one(consts, *xs):
+                flat = jex_core.jaxpr_as_fun(
+                    jex_core.ClosedJaxpr(jaxpr, consts))(*xs)
+                return jax.tree.unflatten(out_tree, flat)
+
+            n = len(args)
+            f = jax.jit(jax.shard_map(
+                jax.vmap(one, in_axes=(None,) + (0,) * n), mesh=self.mesh,
+                in_specs=(P(),) + (P("data"),) * n, out_specs=P("data"),
+                check_vma=False))
+            prog = self._programs[sig] = (
+                f, [self.place(c) for c in closed.consts])
+        return prog
+
+    def __call__(self, *args):
+        f, consts = self._program(args)
+        return f(consts, *args)
+
+    def lower(self, *args):
+        f, consts = self._program(args)
+        return f.lower(consts, *args)
 
 
 def merge_shard_topk(parts, *, k: int):
@@ -152,6 +204,11 @@ class ShardedQueryEngine:
         #: LRU-bounded for the same reason (entries also die eagerly with
         #: their source array via the weakref callback).
         self._chunk_cache: LRU = LRU(max_chunk_entries)
+        #: id(closed-over array) -> (weakref, copy replicated on the mesh):
+        #: one placement per index array however many programs close over
+        #: it (dies with its source array, like the chunk cache)
+        self._replicas: dict = {}
+        self._replicated = NamedSharding(self.mesh, P())
         # counters are registry series (one source of truth for stats());
         # tracer/recorder are attached by the serving layer or the
         # descriptor's observability flag — NOOP/None by default, so the
@@ -276,12 +333,31 @@ class ShardedQueryEngine:
         self._remember(arr, plan, pieces)
         return pieces
 
+    def _place(self, x):
+        """A stage's closed-over array as a program argument: as is when
+        it already lives on exactly the mesh's devices, else replicated
+        over them once, while the array lives."""
+        devices = set(self.mesh.devices.flat)
+        if isinstance(x, jax.Array) and x.sharding.device_set == devices:
+            return x
+        ent = self._replicas.get(id(x))
+        if ent is not None and ent[0]() is x:
+            return ent[1]
+        rep = jax.device_put(x, self._replicated)
+        try:
+            ref = weakref.ref(
+                x, lambda _, k=id(x): self._replicas.pop(k, None))
+        except TypeError:
+            return rep                            # non-weakrefable constant
+        self._replicas[id(x)] = (ref, rep)
+        return rep
+
     # -- the jit cache ------------------------------------------------------
     def _jitted(self, key, fn, bucket: int, sig) -> Callable:
         jk = (key, bucket, sig)
         vf = self._jit_cache.get(jk)
         if vf is None:
-            vf = jax.jit(jax.vmap(fn))
+            vf = data_parallel(fn, self.mesh, self._place)
             self._jit_cache.put(jk, vf)
             ck = (key, sig)
             prior = self.compiles.get(ck, 0) or 0
@@ -289,6 +365,19 @@ class ShardedQueryEngine:
             self._note_compile("cold_rung" if prior == 0 else "ladder_miss",
                               key, bucket)
         return vf
+
+    def compiled_text(self, key, bucket: int) -> str | None:
+        """Optimised HLO of the compiled jit entry for stage ``key`` at
+        ``bucket`` — lowered again from the entry's argument signature, so
+        a caller can see what the device runs (e.g. whether a Pallas kernel
+        is in it as a ``tpu_custom_call``).  None if no such entry."""
+        for (k, b, sig), vf in self._jit_cache.items():
+            if k == key and b == bucket:
+                args = [jax.ShapeDtypeStruct((b,) + shape, jnp.dtype(dtype),
+                                             sharding=self._sharding)
+                        for shape, dtype in sig]
+                return vf.lower(*args).compile().as_text()
+        return None
 
     def max_compiles_per_stage(self) -> int:
         return max(self.compiles.values(), default=0)
@@ -403,7 +492,7 @@ class ShardedQueryEngine:
         key, fn = program.key, program.fn
         sig = tuple((tuple(a.shape[1:]), str(a.dtype)) for a in args)
         pieces = [self._pieces(a, plan) for a in args]
-        anon_vf = jax.jit(jax.vmap(fn)) if key is None else None
+        anon_vf = data_parallel(fn, self.mesh, self._place) if key is None else None
         outs = []
         for i, (start, n, bucket) in enumerate(plan):
             # keyless calls compile fresh and stay out of the persistent

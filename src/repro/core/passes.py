@@ -242,7 +242,7 @@ class PassContext:
                 "compile_tuning_total",
                 "fusion-gate and autotune work per compile", ("counter",)),
             ("gate_estimates", "probe_measurements",
-             "profile_hits", "profile_misses"))
+             "profile_hits", "profile_misses", "gate_errors"))
 
 
 class Pass:
@@ -563,12 +563,14 @@ def _abstract_sds(tree):
 
 
 def _abstract_args(backend):
+    """``((index,), (terms, weights))`` — a candidate's per-backend and
+    per-query arguments, as every gate ``args`` pair is split."""
     import jax
     import jax.numpy as jnp
     idx = _abstract_sds(backend.index)
     t = jax.ShapeDtypeStruct((GATE_MAXQ,), jnp.int32)
     w = jax.ShapeDtypeStruct((GATE_MAXQ,), jnp.float32)
-    return idx, t, w
+    return (idx,), (t, w)
 
 
 def _abstract_qvec(backend):
@@ -578,11 +580,18 @@ def _abstract_qvec(backend):
 
 
 def _abstract_dense_rerank_args(backend):
-    """(index, doc embeddings, terms, weights, query vector) — the per-query
+    """((index, doc embeddings), (terms, weights, query vector)) — the
     signature of the fused/unfused dense-rerank candidates."""
-    idx, t, w = _abstract_args(backend)
+    (idx,), (t, w) = _abstract_args(backend)
     emb = _abstract_sds(backend.dense.emb)
-    return idx, emb, t, w, _abstract_qvec(backend)
+    return (idx, emb), (t, w, _abstract_qvec(backend))
+
+
+def _error_line(exc: BaseException) -> str:
+    """``ExcType: first line`` — what a gate decision records when a
+    candidate fails to lower, compile or run."""
+    first = (str(exc).strip().splitlines() or [""])[0]
+    return f"{type(exc).__name__}: {first}"
 
 
 def _estimate(backend, desc: BackendDescriptor, key, build, args,
@@ -590,6 +599,13 @@ def _estimate(backend, desc: BackendDescriptor, key, build, args,
     """Cost estimate for one candidate per-query program, cached on the
     backend by content key (compilation dominates; estimates are pure
     functions of backend + static params + the descriptor's peaks).
+    ``args`` is ``(static, queries)``: the program is priced as the engine
+    runs it, vmapped over a batch of one query with the static (index)
+    arguments unbatched.  (A 1-D ``lax.top_k`` over a whole collection
+    also takes the TPU compiler ~30 s, against ~1 s batched.)
+    Returns ``(estimate, None)``, or ``(None, error line)`` when the
+    candidate cannot be lowered or compiled — the caller records that in
+    its decision instead of treating it as a cost verdict.
 
     The cache is scoped by the descriptor's host/peak digest: an estimate
     priced under one set of peak constants (or computed on another host and
@@ -603,13 +619,18 @@ def _estimate(backend, desc: BackendDescriptor, key, build, args,
     if counters is not None:
         counters["gate_estimates"] += 1
     try:
-        fn = build()
-        est = estimate_callable(
-            fn, *args, peaks=(desc.peak_flops_per_s, desc.peak_bytes_per_s))
-    except Exception:          # lowering unavailable: never fuse blind
-        est = None
-    cache[key] = est
-    return est
+        import jax
+        static, queries = args
+        fn = jax.vmap(build(),
+                      in_axes=(None,) * len(static) + (0,) * len(queries))
+        one = [jax.ShapeDtypeStruct((1,) + q.shape, q.dtype) for q in queries]
+        out = (estimate_callable(
+            fn, *static, *one,
+            peaks=(desc.peak_flops_per_s, desc.peak_bytes_per_s)), None)
+    except Exception as e:     # recorded in the decision, never fused blind
+        out = (None, _error_line(e))
+    cache[key] = out
+    return out
 
 
 def _backend_gate_digest(backend) -> str:
@@ -797,7 +818,7 @@ class FusionPass(Pass):
                                               pq_shortlist=r))
             if self._gate(pctx, "pq_topk",
                           kernel_native=desc.kernel_native("pq_topk", r),
-                          args=(_abstract_sds(pqi), qv),
+                          args=((_abstract_sds(pqi),), (qv,)),
                           unfused=("pq_topk_unfused", k_in, nprobe, refine),
                           fused=("pq_topk_fused", K, nprobe, refine, r),
                           build_unfused=lambda: (
@@ -814,14 +835,14 @@ class FusionPass(Pass):
         fused = leaf(S.FusedDenseRetrieve(k=K, nprobe=nprobe))
         if nprobe:
             npb = min(nprobe, be.ivf.n_lists)
-            args = (_abstract_sds(be.ivf), qv)
+            args = ((_abstract_sds(be.ivf),), (qv,))
             build_u = lambda: (lambda ivf, q: DN.ivf_retrieve_topk(
                 ivf, q, k=k_in, nprobe=npb))
             build_f = lambda: (lambda ivf, q: DN.ivf_retrieve_topk_fused(
                 ivf, q, k=K, nprobe=npb))
             probe = lambda n: ((be.ivf,), (_probe_qvecs(be, n),))
         else:
-            args = (_abstract_sds(be.dense), qv)
+            args = ((_abstract_sds(be.dense),), (qv,))
             build_u = lambda: (lambda dn, q: DN.dense_retrieve_exact(
                 dn, q, k=k_in))
             build_f = lambda: (lambda dn, q: DN.dense_retrieve_exact_fused(
@@ -935,12 +956,19 @@ class FusionPass(Pass):
                 pctx.decisions.append(d)
                 return bool(d["accepted"])
             pctx.counters["profile_misses"] += 1
-        est_u = _estimate(be, desc, unfused, build_unfused, args,
-                          counters=pctx.counters)
-        est_f = _estimate(be, desc, fused, build_fused, args,
-                          counters=pctx.counters)
+        est_u, err_u = _estimate(be, desc, unfused, build_unfused, args,
+                                 counters=pctx.counters)
+        est_f, err_f = _estimate(be, desc, fused, build_fused, args,
+                                 counters=pctx.counters)
         d = self._decide(pctx, desc, est_u, est_f, build_unfused,
                          build_fused, probe, require_measured)
+        errors = [f"{side}: {e}" for side, e in
+                  (("unfused", err_u), ("fused", err_f)) if e is not None]
+        if d.get("error"):
+            errors.append(d["error"])
+        if errors:
+            pctx.counters["gate_errors"] += len(errors)
+            d["error"] = "; ".join(errors)
         d.update({
             "pattern": pattern, "kernel_native": kernel_native,
             "unfused_key": unfused, "fused_key": fused,
@@ -952,14 +980,15 @@ class FusionPass(Pass):
             "fused_bytes": None if est_f is None else est_f["bytes_per_chip"],
         })
         pctx.decisions.append(d)
-        if prof is not None:
+        if prof is not None and not errors:   # a failure is not replayed
             prof.record(bd, opk, GATE_MAXQ, d)
         return d["accepted"]
 
     def _decide(self, pctx, desc, est_u, est_f, build_unfused, build_fused,
                 probe, require_measured: bool = False) -> dict:
         """Static policy: accept iff the fused estimate prices *strictly*
-        cheaper (lowering failure on either side -> never fuse blind).
+        cheaper (lowering failure on either side -> never fuse blind; the
+        gate records the failure as the decision's ``error``).
         Semantics-affecting candidates (``require_measured``) are never
         taken on estimates alone, so the static gate rejects them."""
         accepted = (not require_measured
@@ -1000,8 +1029,9 @@ class AutotunePass(FusionPass):
                                     batched_args, desc.probe_repeats)
             m_f = _measure_callable(build_fused(), static_args,
                                     batched_args, desc.probe_repeats)
-        except Exception:
-            return d               # probe failure: fall back to the estimate
+        except Exception as e:     # keep the estimate, record the failure
+            d["error"] = f"probe: {_error_line(e)}"
+            return d
         pctx.counters["probe_measurements"] += 2
         d.update({"accepted": bool(m_f < m_u), "source": "measured",
                   "unfused_measured_s": m_u, "fused_measured_s": m_f})
@@ -1106,8 +1136,14 @@ class AutotunePass(FusionPass):
                     jax.block_until_ready(vf(index, qvecs))
                     best = min(best, time.perf_counter() - t0)
                 times[c] = best
-        except Exception:
-            return None            # probe failure: keep the configured knob
+        except Exception as e:     # keep the configured knob, record why
+            pctx.counters["gate_errors"] += 1
+            pctx.decisions.append({
+                "pattern": pattern, "knob": knob, "configured": configured,
+                "candidates": list(cands), "chosen": None,
+                "accepted": False, "source": "measured",
+                "error": f"probe: {_error_line(e)}"})
+            return None
         pctx.counters["probe_measurements"] += len(cands)
         ref = docs[cands[-1]]
 

@@ -517,7 +517,9 @@ def greedy_generate_fn(cfg, *, max_prompt_len: int, max_new_tokens: int):
 
     def gen(params, prompts):
         B = prompts.shape[0]
-        cache = tlm.init_kv_cache(cfg, B, P + T)
+        # the serving pools' cache length, so both attend over the same
+        # (masked) key range and sum it in the same order
+        cache = tlm.init_kv_cache(cfg, B, P + T + 1)
         logits, cache = tlm.prefill(cfg, params, prompts, cache)
         first = jnp.argmax(logits, -1).astype(jnp.int32)
 
@@ -594,11 +596,12 @@ class DenseRerank(Transformer):
         super().__init__(alpha=alpha)
 
     def execute(self, ctx, Q, R):
+        from repro.kernels.dense_scoring.ref import dense_scores
         qvecs = ctx.backend.embed_queries(Q)                  # [NQ, dim]
         emb = ctx.backend.dense.emb
 
         def one(qv, docids, scores):
-            d = emb[jnp.maximum(docids, 0)] @ qv
+            d = dense_scores(emb[jnp.maximum(docids, 0)], qv)
             return jnp.where(docids >= 0,
                              self.params["alpha"] * scores + d, -jnp.inf)
 

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.index.inverted import InvertedIndex
+from repro.kernels.dense_scoring.ref import dense_scores
 
 #: mask score for padded / invalid candidate rows — same constant the
 #: streaming kernels use, so fused and unfused paths rank identically
@@ -65,25 +66,42 @@ class DenseIndex:
 
 def build_dense_index(index: InvertedIndex, dim: int = 64, seed: int = 0,
                       chunk: int = 1 << 21) -> DenseIndex:
-    """Random-projection doc embeddings from the forward file (host loop
-    over doc chunks to bound memory)."""
+    """Random-projection doc embeddings from the forward file: each doc's
+    ``sum_t proj[t] * log1p(tf_t)``, unit-normalised.  Built on the device
+    where the forward file lives, in ranges of ``chunk`` forward entries
+    (``proj[fwd_terms]`` whole would be an [nnz, dim] buffer, tens of GB at
+    Robust scale): each range is one scatter-add into the doc rows."""
     rng = np.random.default_rng(seed)
-    proj = rng.standard_normal((index.vocab, dim)).astype(np.float32) / np.sqrt(dim)
-    fwd_start = np.asarray(index.fwd_start)
-    fwd_terms = np.asarray(index.fwd_terms)
-    fwd_tfs = np.asarray(index.fwd_tfs).astype(np.float32)
-    D = index.n_docs
-    emb = np.zeros((D, dim), np.float32)
-    doc_of = np.repeat(np.arange(D), np.diff(fwd_start))
-    # chunk the scatter: proj[fwd_terms] would otherwise materialise an
-    # [nnz, dim] buffer (tens of GB at Robust scale)
-    F = fwd_terms.shape[0]
-    for s in range(0, F, chunk):
-        e = min(s + chunk, F)
-        np.add.at(emb, doc_of[s:e],
-                  proj[fwd_terms[s:e]] * np.log1p(fwd_tfs[s:e])[:, None])
-    emb /= np.maximum(np.linalg.norm(emb, axis=1, keepdims=True), 1e-6)
-    return DenseIndex(jnp.asarray(emb), dim)
+    proj = jnp.asarray(rng.standard_normal((index.vocab, dim))
+                       .astype(np.float32) / np.sqrt(dim))
+    fwd_start = jnp.asarray(index.fwd_start)
+    fwd_terms = jnp.asarray(index.fwd_terms)
+    fwd_tfs = jnp.asarray(index.fwd_tfs)
+    n = int(fwd_terms.shape[0])
+    size = max(1, min(chunk, n))
+    emb = jnp.zeros((index.n_docs, dim), jnp.float32)
+    for s in range(0, n, size):
+        pad = (0, size - min(size, n - s))
+        emb = _embed_range(emb, proj, fwd_start,
+                           jnp.pad(fwd_terms[s:s + size], pad),
+                           jnp.pad(fwd_tfs[s:s + size], pad), s)
+    return DenseIndex(_unit_rows(emb), dim)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _embed_range(emb, proj, fwd_start, terms, tfs, start):
+    """Add one range of forward entries (zero-padded past the file's end)
+    into their documents' rows."""
+    pos = start + jnp.arange(terms.shape[0])
+    doc = jnp.searchsorted(fwd_start, pos, side="right") - 1
+    doc = jnp.where(pos < fwd_start[-1], doc, emb.shape[0])   # padding: drop
+    contrib = proj[terms] * jnp.log1p(tfs.astype(jnp.float32))[:, None]
+    return emb.at[doc].add(contrib, mode="drop")
+
+
+@jax.jit
+def _unit_rows(emb):
+    return emb / jnp.maximum(jnp.linalg.norm(emb, axis=1, keepdims=True), 1e-6)
 
 
 def embed_query(dense: DenseIndex, index: InvertedIndex, terms, weights,
@@ -99,14 +117,15 @@ def embed_query(dense: DenseIndex, index: InvertedIndex, terms, weights,
 
 @partial(jax.jit, static_argnames=("k",))
 def dense_topk(dense: DenseIndex, qvec: jax.Array, *, k: int):
-    scores = dense.emb @ qvec
+    scores = dense_scores(dense.emb, qvec)
     top_s, top_d = jax.lax.top_k(scores, k)
     return top_d.astype(jnp.int32), top_s
 
 
 @jax.jit
 def dense_score(dense: DenseIndex, qvec: jax.Array, docids: jax.Array):
-    return jnp.where(docids >= 0, dense.emb[jnp.maximum(docids, 0)] @ qvec, 0.0)
+    return jnp.where(docids >= 0,
+                     dense_scores(dense.emb[jnp.maximum(docids, 0)], qvec), 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -484,7 +503,7 @@ def _pq_finish(pq: IVFPQIndex, qvec, pos_r, vals_a, *, k: int):
     ok = vals_a > NEG / 2
     docs = pq.doc_ids[pos_r]
     if pq.emb is not None:
-        vals = jnp.where(ok, pq.emb[docs] @ qvec, NEG)
+        vals = jnp.where(ok, dense_scores(pq.emb[docs], qvec), NEG)
     else:
         vals = jnp.where(ok, vals_a, NEG)
     top_v, sel = jax.lax.top_k(vals, k)

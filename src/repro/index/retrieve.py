@@ -228,8 +228,10 @@ def retrieve_dense_rerank(index: InvertedIndex, emb, terms, weights, qvec, *,
     below must reproduce exactly."""
     docs, scores = retrieve_topk(index, terms, weights, model=model, k=k_in,
                                  max_postings=max_postings)
+    from repro.kernels.dense_scoring.ref import dense_scores
     ds = jnp.where(docs >= 0,
-                   alpha * scores + emb[jnp.maximum(docs, 0)] @ qvec,
+                   alpha * scores + dense_scores(emb[jnp.maximum(docs, 0)],
+                                                 qvec),
                    -jnp.inf)
     order = jnp.argsort(-ds)
     return docs[order][:k].astype(jnp.int32), ds[order][:k]

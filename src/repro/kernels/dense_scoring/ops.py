@@ -9,7 +9,7 @@ from repro.common import round_up
 from repro.kernels.dense_scoring.dense_scoring import (BLOCK_D,
                                                        dense_topk_pallas)
 from repro.kernels.dense_scoring.ref import dense_topk_ref
-from repro.kernels.topk.topk import NEG
+from repro.kernels.topk.topk import LANES, NEG
 
 MAX_KERNEL_K = 128
 
@@ -27,14 +27,16 @@ def streaming_dense_topk(emb, qvec, base=None, *, k: int,
                          interpret: bool = False):
     """Top-k of ``emb @ qvec + base`` (base defaults to 0) without ever
     materialising + sorting the full score vector on the kernel path.
-    Returns values sorted descending + their row indices into ``emb``;
-    padded rows score ``NEG`` and can never enter the top-k of real
-    candidates."""
+    Returns values sorted descending (ties to the lowest index) + their
+    row indices into ``emb``; padded rows score ``NEG`` and can never enter
+    the top-k of real candidates.  ``block`` is rounded up to whole
+    128-lane rows."""
     if impl == "auto":
         impl = "pallas" if (jax.default_backend() == "tpu" and
                             k <= MAX_KERNEL_K) else "ref"
     if impl == "ref" or k > MAX_KERNEL_K:
         return dense_topk_ref(emb, qvec, base, k=k)
+    block = round_up(block, LANES)
     n, dim = emb.shape
     n_pad = round_up(max(n, block), block)
     if base is None:

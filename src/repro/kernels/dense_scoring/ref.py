@@ -13,8 +13,20 @@ import jax
 import jax.numpy as jnp
 
 
+#: every dense score is contracted at full f32 precision — the TPU's default
+#: f32 matmul rounds its operands to bf16 — so the kernel, the unfused
+#: chains and the serving path rank candidates identically
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def dense_scores(emb, qvec):
+    """``emb @ qvec`` in f32 at :data:`PRECISION`."""
+    return jnp.dot(emb.astype(jnp.float32), qvec.astype(jnp.float32),
+                   precision=PRECISION)
+
+
 def dense_topk_ref(emb, qvec, base=None, *, k: int):
-    scores = emb.astype(jnp.float32) @ qvec.astype(jnp.float32)
+    scores = dense_scores(emb, qvec)
     if base is not None:
         scores = scores + base
     vals, idxs = jax.lax.top_k(scores, k)

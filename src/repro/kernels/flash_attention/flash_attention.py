@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 DEFAULT_BQ = 512
 DEFAULT_BKV = 512
 NEG = -1e30
@@ -117,7 +115,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, chunk: int = 0,
             pltpu.VMEM((bq,), jnp.float32),      # l: running denominator
             pltpu.VMEM((bq, D), jnp.float32),    # acc: running numerator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

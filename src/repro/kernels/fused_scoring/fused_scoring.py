@@ -4,8 +4,11 @@ One VMEM-resident postings tile (tf, doc_len, df, cf) produces F weighting-
 model scores — the fat-postings insight as arithmetic-intensity: postings are
 read from HBM once and every model's math runs on the registers/VMEM tile.
 
-Grid: postings blocks of ``BLOCK_P`` rows; per block the kernel emits a
-[BLOCK_P, F] score tile.  Pure VPU math (no MXU), bf16-safe in fp32 compute.
+Grid: postings blocks of ``BLOCK_P`` postings, laid out as ``[BLOCK_P/128,
+128]`` tiles; per block the kernel emits an ``[F, BLOCK_P/128, 128]`` score
+tile, which the wrapper transposes back to ``[N, F]``.  Every block keeps
+(8, 128)-legal trailing dims, also under the engine's vmap.  Pure VPU math
+(no MXU), bf16-safe in fp32 compute.
 """
 from __future__ import annotations
 
@@ -16,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.index.scoring import BM25_B, BM25_K1, QL_MU
+from repro.kernels.topk.topk import LANES, leading_batch
 
 BLOCK_P = 2048
 
@@ -24,7 +28,7 @@ SUPPORTED = ("BM25", "TF_IDF", "QL", "DPH", "Coord")
 
 
 def _model_scores(model, tf, dl, df, cf, n_docs, avg_dl, total_terms):
-    """fp32 scalar math for one model over a [BLOCK_P] tile."""
+    """fp32 elementwise math for one model over a postings tile."""
     if model == "BM25":
         idf = jnp.log1p((n_docs - df + 0.5) / (df + 0.5))
         denom = tf + BM25_K1 * (1 - BM25_B + BM25_B * dl / avg_dl)
@@ -56,13 +60,13 @@ def _model_scores(model, tf, dl, df, cf, n_docs, avg_dl, total_terms):
 
 def _kernel(tf_ref, dl_ref, df_ref, cf_ref, out_ref, *, models, n_docs,
             avg_dl, total_terms):
-    tf = tf_ref[...].astype(jnp.float32)
+    tf = tf_ref[...].astype(jnp.float32)                 # [rows, 128]
     dl = dl_ref[...].astype(jnp.float32)
     df = df_ref[...].astype(jnp.float32)
     cf = cf_ref[...].astype(jnp.float32)
     for j, m in enumerate(models):
         s = _model_scores(m, tf, dl, df, cf, n_docs, avg_dl, total_terms)
-        out_ref[:, j] = jnp.where(tf > 0, s, 0.0)
+        out_ref[j] = jnp.where(tf > 0, s, 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("models", "n_docs", "avg_dl",
@@ -73,16 +77,21 @@ def fused_scoring_pallas(tf, dl, df, cf, *, models: tuple[str, ...],
     """tf/dl/df/cf: [N] (N % BLOCK_P == 0) -> scores [N, F] fp32."""
     n = tf.shape[0]
     assert n % BLOCK_P == 0, n
-    grid = (n // BLOCK_P,)
+    rows = BLOCK_P // LANES
+    n_models = len(models)
     kernel = functools.partial(_kernel, models=models, n_docs=float(n_docs),
                                avg_dl=float(avg_dl),
                                total_terms=float(total_terms))
-    in_spec = pl.BlockSpec((BLOCK_P,), lambda i: (i,))
-    return pl.pallas_call(
+    tile = lambda x: x.reshape(n // LANES, LANES)
+    in_spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
+    out = leading_batch(pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n // BLOCK_P,),
         in_specs=[in_spec] * 4,
-        out_specs=pl.BlockSpec((BLOCK_P, len(models)), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, len(models)), jnp.float32),
+        out_specs=pl.BlockSpec((n_models, rows, LANES), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_models, n // LANES, LANES),
+                                       jnp.float32),
         interpret=interpret,
-    )(tf, dl, df, cf)
+        name="fused_scoring",
+    ))(tile(tf), tile(dl), tile(df), tile(cf))
+    return out.reshape(n_models, n).T

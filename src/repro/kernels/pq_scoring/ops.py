@@ -8,9 +8,9 @@ import jax.numpy as jnp
 from repro.common import round_up
 from repro.kernels.pq_scoring.pq_scoring import BLOCK_C, pq_topk_pallas
 from repro.kernels.pq_scoring.ref import pq_topk_ref
-from repro.kernels.topk.topk import NEG
+from repro.kernels.topk.topk import LANES, NEG
 
-MAX_KERNEL_K = 128
+MAX_KERNEL_K = 1024
 
 
 def kernel_native(k: int) -> bool:
@@ -29,12 +29,13 @@ def streaming_pq_topk(codes, table, base=None, *, k: int,
     vector on the kernel path.  Returns values sorted descending (ties to
     the lowest index, matching ``lax.top_k``) + their row indices into
     ``codes``; padded rows score ``NEG`` and can never enter the top-k of
-    real candidates."""
+    real candidates.  ``block`` is rounded up to whole 128-lane rows."""
     if impl == "auto":
         impl = "pallas" if (jax.default_backend() == "tpu" and
                             k <= MAX_KERNEL_K) else "ref"
     if impl == "ref" or k > MAX_KERNEL_K:
         return pq_topk_ref(codes, table, base, k=k)
+    block = round_up(block, LANES)
     n, m = codes.shape
     n_pad = round_up(max(n, block), block)
     if base is None:
@@ -43,5 +44,5 @@ def streaming_pq_topk(codes, table, base=None, *, k: int,
     base_p = jnp.pad(base.astype(jnp.float32), (0, n_pad - n),
                      constant_values=NEG)
     return pq_topk_pallas(
-        codes_p, table.astype(jnp.float32), base_p, k=k, block=block,
+        codes_p.T, table.astype(jnp.float32), base_p, k=k, block=block,
         interpret=interpret or jax.default_backend() != "tpu")

@@ -23,6 +23,9 @@ from repro.configs.registry import all_arch_ids, get_arch
 from repro.launch import mesh as mesh_lib
 from repro.launch.steps import build_bundle
 
+#: the chip the production meshes are priced on
+_V5E = hlo_cost.device_peaks("TPU v5 lite")
+
 # ---------------------------------------------------------------------------
 # dry-run of one cell
 # ---------------------------------------------------------------------------
@@ -77,9 +80,9 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
 
     # roofline terms (seconds); cost_analysis FLOPs/bytes are per-chip
     rec["model_flops"] = bundle.model_flops_per_step
-    rec["t_compute"] = rec["hlo_flops_per_chip"] / mesh_lib.PEAK_FLOPS_BF16
-    rec["t_memory"] = rec["hlo_bytes_per_chip"] / mesh_lib.HBM_BW
-    rec["t_collective"] = rec["collective_bytes_per_chip"] / mesh_lib.ICI_BW
+    rec["t_compute"] = rec["hlo_flops_per_chip"] / _V5E.flops_per_s
+    rec["t_memory"] = rec["hlo_bytes_per_chip"] / _V5E.bytes_per_s
+    rec["t_collective"] = rec["collective_bytes_per_chip"] / _V5E.ici_bytes_per_s
     terms = {"compute": rec["t_compute"], "memory": rec["t_memory"],
              "collective": rec["t_collective"]}
     rec["bottleneck"] = max(terms, key=terms.get)

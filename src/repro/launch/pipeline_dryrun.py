@@ -21,6 +21,9 @@ from repro.analysis import hlo_cost
 from repro.index import scoring
 from repro.launch import mesh as mesh_lib
 
+#: the chip the production meshes are priced on
+_V5E = hlo_cost.device_peaks("TPU v5 lite")
+
 # ClueWeb09-scale descriptors (never materialised: ShapeDtypeStructs only)
 N_DOCS = 50_220_423
 MAXQ = 32
@@ -99,9 +102,9 @@ def main():
         "collective_bytes_per_chip": walk["collective_bytes_per_chip"],
         "collectives": walk["collectives"],
         "temp_bytes": int(mem.temp_size_in_bytes),
-        "t_compute": walk["flops_per_chip"] / mesh_lib.PEAK_FLOPS_BF16,
-        "t_memory": walk["bytes_per_chip"] / mesh_lib.HBM_BW,
-        "t_collective": walk["collective_bytes_per_chip"] / mesh_lib.ICI_BW,
+        "t_compute": walk["flops_per_chip"] / _V5E.flops_per_s,
+        "t_memory": walk["bytes_per_chip"] / _V5E.bytes_per_s,
+        "t_collective": walk["collective_bytes_per_chip"] / _V5E.ici_bytes_per_s,
     }
     tag = "ir_pipeline__" + rec["mesh"]
     Path(args.out).mkdir(parents=True, exist_ok=True)

@@ -283,9 +283,14 @@ def test_nprobe_autotune_measures_then_replays(small_ir):
 
 @pytest.mark.parametrize("n_shards", [1, 2, 4])
 def test_doc_shard_merge_matches_single_shard_oracle(small_ir, n_shards):
-    """Per-shard top-k + cross-shard merge through the engine is
-    bit-identical to the single-shard run (and the traced lax merge
-    agrees)."""
+    """Per-shard top-k + cross-shard merge through the engine returns the
+    single-shard run's doc ids exactly, and its scores to within the
+    float32 reordering bound of a dot product (the traced lax merge agrees
+    the same way).  XLA blocks the ``[N, dim] @ [dim]`` product by the row
+    count N, so a row's dot product can be summed in a different order in
+    a shard than in the whole index (measured: 1.19e-7, one ULP at 1.0).
+    Two summation orders of a length-d dot product of unit vectors differ
+    by at most ``2 * d * 2**-24``."""
     import jax
     import jax.numpy as jnp
 
@@ -310,14 +315,16 @@ def test_doc_shard_merge_matches_single_shard_oracle(small_ir, n_shards):
 
     oracle = eng.run_doc_sharded(progs_for(1), None, qvecs, k=k)
     docs, vals = eng.run_doc_sharded(progs_for(n_shards), None, qvecs, k=k)
+    reorder = 2 * dense.dim * 2.0 ** -24
     np.testing.assert_array_equal(docs, oracle[0])
-    np.testing.assert_array_equal(vals, oracle[1])
+    np.testing.assert_allclose(vals, oracle[1], rtol=0, atol=reorder)
 
     shards = shard_dense_index(dense, n_shards)
     dt, vt = jax.jit(jax.vmap(
         lambda q: sharded_dense_topk(shards, q, k=k)))(qvecs)
     np.testing.assert_array_equal(np.asarray(dt), oracle[0])
-    np.testing.assert_array_equal(np.asarray(vt), oracle[1])
+    np.testing.assert_allclose(np.asarray(vt), oracle[1], rtol=0,
+                               atol=reorder)
 
 
 # ---------------------------------------------------------------------------

@@ -67,6 +67,42 @@ def test_peak_digest_tracks_calibration():
     assert d.peak_digest != d2.peak_digest
 
 
+def test_device_peak_table_keyed_by_device_kind():
+    from repro.analysis.hlo_cost import device_peaks
+    v5e = device_peaks("TPU v5 lite")
+    assert (v5e.flops_per_s, v5e.bytes_per_s) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    assert "uncalibrated proxy" in device_peaks("cpu").source
+    # the CPU row keeps the constants the CPU gate decisions were made with
+    d = BackendDescriptor.default()
+    assert (d.peak_flops_per_s, d.peak_bytes_per_s) == (1.0e14, 1.0e12)
+
+
+def test_unknown_tpu_device_kind_raises():
+    from repro.analysis.hlo_cost import device_peaks
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_peaks("TPU v99")
+
+
+def test_fused_candidate_lowering_error_is_recorded(env, monkeypatch):
+    """A fused candidate that cannot lower is declined with the failure on
+    the decision (``error``) and the pass counters — never passed off as a
+    cost verdict for the unfused chain."""
+    from repro.index import retrieve as RT
+
+    def broken(*a, **kw):
+        raise NotImplementedError("no lowering for this kernel\nsecond line")
+
+    monkeypatch.setattr(RT, "retrieve_topk_fused", broken)
+    op, rep = _compile(_backend(env, autotune=False))
+    assert op.kind != "fused_topk_retrieve"
+    (d,) = [d for d in rep["fusion_decisions"] if d["pattern"] == "topk"]
+    assert d["accepted"] is False
+    assert d["error"] == ("fused: NotImplementedError: no lowering for "
+                          "this kernel")
+    assert rep["tuning"]["gate_errors"] == 1
+
+
 def test_capabilities_kwarg_removed(env):
     """The pre-descriptor ``capabilities=`` ctor kwarg finished its
     deprecation cycle: it now fails like any unknown kwarg, and the

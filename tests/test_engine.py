@@ -345,3 +345,52 @@ def test_engine_chunk_cache_reused_across_stages(small_ir):
     (Retrieve("BM25", k=20) >> Extract("QL") >> Extract("TF_IDF")) \
         .transform(env["Q"], backend=be, optimize=False)
     assert be.engine.n_chunk_cache_hits > 0
+
+
+# ---------------------------------------------------------------------------
+# device placement: meshes, hoisted index arrays, the compile cache
+# ---------------------------------------------------------------------------
+
+def test_query_mesh_axes_are_auto():
+    """Auto axes: GSPMD may propagate the query sharding through a stage's
+    gathers from replicated index arrays (explicit axes reject them)."""
+    import jax
+    from repro.launch.mesh import make_host_mesh, make_query_mesh
+    for mesh in (make_query_mesh(), make_query_mesh(doc_shards=1),
+                 make_host_mesh()):
+        assert set(mesh.axis_types) == {jax.sharding.AxisType.Auto}
+
+
+def test_closed_over_arrays_are_program_arguments_not_constants():
+    """A stage's closed-over index enters its program as an argument: the
+    lowered program stays small however large the index is, and one
+    placement serves every program closing over the same array."""
+    import jax.numpy as jnp
+    big = jnp.arange(1 << 20, dtype=jnp.float32)        # 4 MiB "index"
+    eng = ShardedQueryEngine(ladder=(8,))
+    terms = np.arange(5, dtype=np.int32)
+    for key in ("a", "b"):
+        out = eng.run(StageProgram(key=key, fn=lambda t: big[t] * 2.0),
+                      None, terms)
+        np.testing.assert_array_equal(np.asarray(out), 2.0 * terms)
+    vf = eng._jit_cache.values()[0]
+    text = vf.lower(jnp.zeros((8,), jnp.int32)).as_text()
+    assert len(text) < 100_000               # an embedded copy is ~20 MB
+    assert len(eng._replicas) <= 1
+
+
+def test_compile_cache_stays_where_the_environment_puts_it(monkeypatch,
+                                                          tmp_path):
+    import jax
+    from repro.launch.cache import REPO_CACHE_DIR, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert use_compile_cache() == str(REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+        assert REPO_CACHE_DIR.name == ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

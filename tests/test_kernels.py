@@ -90,6 +90,28 @@ def test_streaming_pq_topk_sweep(n, m, k, block, with_base):
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
 
+def test_streaming_pq_topk_vmapped_over_a_trailing_query_axis():
+    # the engine vmaps the IVF-PQ stage over queries; the per-query ADC
+    # table comes out of an einsum with the query axis *last*, which the
+    # kernel must move to the front of its blocks
+    rng = np.random.default_rng(3)
+    n, m, nq = 1000, 8, 3
+    codes = jnp.asarray(rng.integers(0, 256, (n, m)).astype(np.uint8))
+    cents = jnp.asarray(rng.standard_normal((m, 256, 4)).astype(np.float32))
+    qs = jnp.asarray(rng.standard_normal((nq, m, 4)).astype(np.float32))
+
+    def one(q, impl):
+        table = jnp.einsum("mcd,md->mc", cents, q)
+        return streaming_pq_topk(codes, table, None, k=16, block=256,
+                                 impl=impl, interpret=True)
+
+    v1, i1 = jax.vmap(lambda q: one(q, "pallas"))(qs)
+    v2, i2 = jax.vmap(lambda q: one(q, "ref"))(qs)
+    np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+
+
 def test_streaming_pq_topk_duplicate_codes():
     # every doc in {0,1} code space: massive score ties.  Like the other
     # streaming kernels, ties deeper than k admit any valid top-k set —
