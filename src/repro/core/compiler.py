@@ -82,6 +82,9 @@ class JaxBackend:
         # stopwords are removed at index time (build_index), so the global
         # max posting-list length is the safe static gather width
         lens = np.diff(np.asarray(index.term_start))
+        #: host copy of each term's (block-padded) posting-list length:
+        #: live posting slots are counted from it, never from the device
+        self.posting_lens = lens
         self.max_postings = int(lens.max())
         self.max_blocks_per_term = self.max_postings // BLOCK
         self.total_blocks = int(index.doc_ids.shape[0]) // BLOCK
@@ -117,6 +120,17 @@ class JaxBackend:
         self.engine = (engine if engine is not None
                        else ShardedQueryEngine(ladder=bucket_ladder)
                        if sharded else None)
+        # posting slots the exhaustive sparse stages gathered for live query
+        # terms (live) or for nothing (pad); kept in the engine's registry,
+        # so backends sharing an engine share the series
+        self._m_slots = None
+        if self.engine is not None:
+            self._m_slots = self.engine.metrics.counter(
+                "retrieve_posting_slots_total",
+                "posting slots gathered by exhaustive sparse stages, live or "
+                "padding", ("kind",))
+            for kind in ("live", "pad"):
+                self._m_slots.touch((kind,))
 
     @property
     def capabilities(self) -> frozenset:
@@ -177,7 +191,7 @@ class JaxBackend:
         return self._ivfpq
 
     # -- query-axis execution ----------------------------------------------
-    def vmap_queries(self, fn, Q, *extra, key=None):
+    def vmap_queries(self, fn, Q, *extra, key=None, postings: bool = False):
         """vmap ``fn(terms, weights, *extra_i)`` over queries.  If Q is None,
         ``fn(*extra_i)`` is mapped over the extra arrays.  Routed through the
         sharded bucketed engine when one is attached (the default); ``key``
@@ -185,11 +199,42 @@ class JaxBackend:
         entry, scoped by this backend's uid — stage keys do not embed index
         contents, so on an engine shared across backends an unscoped key
         would serve one backend's closure-captured index/embeddings to the
-        other.  Falls back to the sequential single-device chunked loop."""
+        other.  ``postings=True`` marks a stage that gathers
+        ``max_postings`` postings per query-term slot: its slots are
+        counted (``retrieve_posting_slots_total``).  Falls back to the
+        sequential single-device chunked loop."""
         if self.engine is not None:
             scoped = None if key is None else (self.uid, key)
-            return self.engine.run(StageProgram(key=scoped, fn=fn), Q, *extra)
+            out = self.engine.run(StageProgram(key=scoped, fn=fn), Q, *extra)
+            if postings:
+                self._count_slots(Q)
+            return out
         return self.vmap_queries_sequential(fn, Q, *extra)
+
+    def _count_slots(self, Q) -> None:
+        """Posting slots of the engine's last dispatch on this thread, from
+        host arrays only: terms still on the device are not counted
+        (reading them would wait for the device)."""
+        terms = Q["terms"]
+        if not isinstance(terms, np.ndarray):
+            return
+        live, rows = self.engine.last_rows()
+        t = terms[:live]
+        slots_live = int(self.posting_lens[t[t >= 0]].sum())
+        self._m_slots.inc(slots_live, ("live",))
+        self._m_slots.inc(rows * terms.shape[1] * self.max_postings
+                          - slots_live, ("pad",))
+
+    def work_counts(self) -> dict:
+        """Totals of the padded-work counters of this backend's engine:
+        ``rows_live``, ``rows_pad``, ``slots_live``, ``slots_pad``.  A
+        caller takes the difference of two reads around its work; that
+        difference is its own only while no other thread dispatches on the
+        engine."""
+        s = self._m_slots
+        return {**self.engine.row_counts(),
+                "slots_live": int(s.value(("live",))),
+                "slots_pad": int(s.value(("pad",)))}
 
     def vmap_queries_sequential(self, fn, Q, *extra):
         """The seed's single-device chunked-vmap loop, kept as the engine's

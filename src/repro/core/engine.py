@@ -32,7 +32,10 @@ the concatenated result.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import re
+import threading
 import weakref
 from typing import Any, Callable, Sequence
 
@@ -53,6 +56,13 @@ def _key_label(key) -> str:
     embed content digests and param tuples — too long for a span arg)."""
     s = str(key)
     return s if len(s) <= 96 else s[:93] + "..."
+
+
+def _program_name(label: str) -> str:
+    """A stage program's function name, from its key label: the compiled
+    module is ``jit_<name>``, so the device trace's "XLA Modules" line
+    attributes device time to the stage."""
+    return re.sub(r"\W+", "_", label).strip("_")[:64] or "stage"
 
 
 def default_bucket_ladder(n_devices: int, *, base: int = 8,
@@ -83,11 +93,14 @@ class data_parallel:
     would carry its own copy of the index in its executable — gigabytes at
     Robust scale, slow to compile and resident on the device once per
     program.  ``place`` puts each such array on the mesh (default: as is).
+    ``name`` names the jitted function (and so the compiled module).
     Callable like the jitted function; :meth:`lower` lowers it."""
 
-    def __init__(self, fn, mesh, place: Callable | None = None):
+    def __init__(self, fn, mesh, place: Callable | None = None,
+                 name: str | None = None):
         self.fn, self.mesh = fn, mesh
         self.place = place if place is not None else (lambda x: x)
+        self.name = name
         self._programs: dict = {}
 
     def _program(self, args):
@@ -103,6 +116,8 @@ class data_parallel:
                     jex_core.ClosedJaxpr(jaxpr, consts))(*xs)
                 return jax.tree.unflatten(out_tree, flat)
 
+            if self.name:
+                one.__name__ = one.__qualname__ = self.name
             n = len(args)
             f = jax.jit(jax.shard_map(
                 jax.vmap(one, in_axes=(None,) + (0,) * n), mesh=self.mesh,
@@ -228,6 +243,14 @@ class ShardedQueryEngine:
         self.metrics.gauge(
             "engine_jit_cache_entries",
             "resident compiled executables").set_fn(lambda: len(self._jit_cache))
+        # padded work: rows each dispatch carried (live) or padded to its
+        # rung (pad)
+        self._m_rows = self.metrics.counter(
+            "engine_rows_total", "rows dispatched, live or padding",
+            ("kind",))
+        for kind in ("live", "pad"):
+            self._m_rows.touch((kind,))
+        self._tls = threading.local()
         self.tracer = NOOP_TRACER
         self.recorder = None
         #: bucket -> EWMA of measured batch service seconds, fed back by the
@@ -246,6 +269,39 @@ class ShardedQueryEngine:
             self.tracer = tracer
         if recorder is not None:
             self.recorder = recorder
+
+    @contextlib.contextmanager
+    def live_rows(self, n: int):
+        """Declare, for this thread's dispatches inside the block, that only
+        the first ``n`` rows of each batch are live: a caller that pads its
+        batch to a rung itself (the server) says where its padding starts.
+        Without it every row a caller passes counts as live."""
+        prev = getattr(self._tls, "live", None)
+        self._tls.live = int(n)
+        try:
+            yield
+        finally:
+            self._tls.live = prev
+
+    def row_counts(self) -> dict:
+        """Totals of ``engine_rows_total``: ``rows_live``, ``rows_pad``."""
+        r = self._m_rows
+        return {"rows_live": int(r.value(("live",))),
+                "rows_pad": int(r.value(("pad",)))}
+
+    def last_rows(self) -> tuple[int, int]:
+        """``(live, dispatched)`` rows of this thread's last
+        :meth:`run`/:meth:`submit_chunk`; ``(0, 0)`` before the first."""
+        return getattr(self._tls, "rows", (0, 0))
+
+    def _count_rows(self, plan) -> None:
+        live = getattr(self._tls, "live", None)
+        nq = plan[-1][0] + plan[-1][1]
+        live = nq if live is None else max(0, min(live, nq))
+        rows = sum(b for _, _, b in plan)
+        self._m_rows.inc(live, ("live",))
+        self._m_rows.inc(rows - live, ("pad",))
+        self._tls.rows = (live, rows)
 
     @property
     def n_compiles_total(self) -> int:
@@ -357,7 +413,8 @@ class ShardedQueryEngine:
         jk = (key, bucket, sig)
         vf = self._jit_cache.get(jk)
         if vf is None:
-            vf = data_parallel(fn, self.mesh, self._place)
+            vf = data_parallel(fn, self.mesh, self._place,
+                               name=_program_name(_key_label(key)))
             self._jit_cache.put(jk, vf)
             ck = (key, sig)
             prior = self.compiles.get(ck, 0) or 0
@@ -504,6 +561,7 @@ class ShardedQueryEngine:
                                   n=n, key=_key_label(key)):
                 outs.append(vf(*[p[i] for p in pieces]))
             self._m_dispatches.inc()
+        self._count_rows(plan)
         full = self._materialize(outs, plan)
         self._remember_outputs(full, outs, plan)
         return full

@@ -45,7 +45,8 @@ class Retrieve(Transformer):
                                     model=model, k=k,
                                     max_postings=ctx.backend.max_postings)
 
-        docs, scores = ctx.backend.vmap_queries(one, Q, key=self.key())
+        docs, scores = ctx.backend.vmap_queries(one, Q, key=self.key(),
+                                                postings=True)
         return Q, {"qid": Q["qid"], "docids": docs, "scores": scores}
 
 
@@ -95,7 +96,8 @@ class MultiRetrieve(Transformer):
                                      models=models, k=k,
                                      max_postings=ctx.backend.max_postings)
 
-        docs, scores = ctx.backend.vmap_queries(one, Q, key=self.key())
+        docs, scores = ctx.backend.vmap_queries(one, Q, key=self.key(),
+                                                postings=True)
         return Q, {"qid": Q["qid"], "docids": docs, "scores": scores}
 
 
@@ -120,7 +122,8 @@ class FatRetrieve(Transformer):
                 feature_models=self.params["features"], k=k,
                 max_postings=ctx.backend.max_postings)
 
-        docs, scores, feats = ctx.backend.vmap_queries(one, Q, key=self.key())
+        docs, scores, feats = ctx.backend.vmap_queries(
+            one, Q, key=self.key(), postings=True)
         return Q, {"qid": Q["qid"], "docids": docs, "scores": scores,
                    "features": feats}
 
@@ -145,7 +148,8 @@ class FusedTopKRetrieve(Transformer):
                                           model=model, k=k,
                                           max_postings=ctx.backend.max_postings)
 
-        docs, scores = ctx.backend.vmap_queries(one, Q, key=self.key())
+        docs, scores = ctx.backend.vmap_queries(one, Q, key=self.key(),
+                                                postings=True)
         return Q, {"qid": Q["qid"], "docids": docs, "scores": scores}
 
 
@@ -170,7 +174,8 @@ class FusedFatRetrieve(Transformer):
                 feature_models=self.params["features"], k=k,
                 max_postings=ctx.backend.max_postings)
 
-        docs, scores, feats = ctx.backend.vmap_queries(one, Q, key=self.key())
+        docs, scores, feats = ctx.backend.vmap_queries(
+            one, Q, key=self.key(), postings=True)
         return Q, {"qid": Q["qid"], "docids": docs, "scores": scores,
                    "features": feats}
 
@@ -287,7 +292,8 @@ class FusedDenseRerank(Transformer):
                 k_in=k_in, k=k, alpha=p["alpha"],
                 max_postings=be.max_postings)
 
-        docs, scores = be.vmap_queries(one, Q, qvecs, key=self.key())
+        docs, scores = be.vmap_queries(one, Q, qvecs, key=self.key(),
+                                       postings=True)
         return Q, {"qid": Q["qid"], "docids": docs, "scores": scores}
 
 

@@ -107,50 +107,29 @@ class _Instrument:
         return "{" + ",".join(parts) + "}" if parts else ""
 
 
-class Counter(_Instrument):
-    """Monotone counter.  ``inc`` only; ``_set`` is reserved for internal
-    views (``CounterMap``) that need dict-style assignment."""
-
-    kind = "counter"
-
-    def inc(self, n: float = 1.0, labels=()) -> None:
-        key = _label_key(labels)
-        self._check(key)
-        with self._lock:
-            self._series[key] = self._series.get(key, 0.0) + n
-
-    def snapshot_value(self, key: tuple):
-        with self._lock:
-            v = float(self._series.get(key, 0.0))
-        return int(v) if v.is_integer() else v
-
-
-class Gauge(_Instrument):
-    """Point-in-time value.  ``set_fn`` registers a pull-style collector:
-    the callable is invoked at snapshot/render time (used to surface LRU
-    cache internals without mirroring every update)."""
-
-    kind = "gauge"
+class _Pulled(_Instrument):
+    """An instrument whose series may also be pulled: ``set_fn``
+    registers a collector, a callable invoked at read time (to surface
+    values kept elsewhere without mirroring every update)."""
 
     def __init__(self, name: str, help: str = "",
                  labelnames: tuple[str, ...] = ()):
         super().__init__(name, help, labelnames)
         self._fns: dict[tuple, Callable[[], float]] = {}
 
-    def set(self, v: float, labels=()) -> None:
-        self._set(labels, v)
-
-    def add(self, n: float = 1.0, labels=()) -> None:
-        key = _label_key(labels)
-        self._check(key)
-        with self._lock:
-            self._series[key] = self._series.get(key, 0.0) + n
-
     def set_fn(self, fn: Callable[[], float], labels=()) -> None:
         key = _label_key(labels)
         self._check(key)
         with self._lock:
             self._fns[key] = fn
+
+    def value(self, labels=()) -> float:
+        key = _label_key(labels)
+        with self._lock:
+            fn = self._fns.get(key)
+            if fn is None:
+                return self._series.get(key, 0.0)
+        return float(fn())
 
     def series(self) -> dict[tuple, float]:
         with self._lock:
@@ -162,6 +141,40 @@ class Gauge(_Instrument):
             except Exception:
                 out.setdefault(key, 0.0)
         return out
+
+
+class Counter(_Pulled):
+    """Monotone counter.  ``inc`` only, or a pulled total (``set_fn``)
+    that only grows; ``_set`` is reserved for internal views
+    (``CounterMap``) that need dict-style assignment."""
+
+    kind = "counter"
+
+    def inc(self, n: float = 1.0, labels=()) -> None:
+        key = _label_key(labels)
+        self._check(key)
+        with self._lock:
+            self._series[key] = self._series.get(key, 0.0) + n
+
+    def snapshot_value(self, key: tuple):
+        v = float(self.value(key))
+        return int(v) if v.is_integer() else v
+
+
+class Gauge(_Pulled):
+    """Point-in-time value, set, added to, or pulled (``set_fn``: used to
+    surface LRU cache internals without mirroring every update)."""
+
+    kind = "gauge"
+
+    def set(self, v: float, labels=()) -> None:
+        self._set(labels, v)
+
+    def add(self, n: float = 1.0, labels=()) -> None:
+        key = _label_key(labels)
+        self._check(key)
+        with self._lock:
+            self._series[key] = self._series.get(key, 0.0) + n
 
 
 class Histogram(_Instrument):

@@ -19,8 +19,16 @@ tracer's epoch.  No wall-clock is recorded, so traces from restarted
 processes never interleave misleadingly (Perfetto renders relative
 time anyway).
 
-The disabled path is one attribute check returning shared no-op
-singletons; a disabled tracer allocates nothing per call.
+The profiler bridge: a live ``span(...)`` also enters a
+``jax.profiler.TraceAnnotation`` of the same name while a profiler
+session runs, so the program's spans land on the device trace's own
+clock, beside the device operations they drive.  That holds with the
+tracer disabled too: then the annotation is all that is left.  With no
+profiler session and the tracer disabled, ``span`` is one attribute
+check plus the profiler's own ``is_enabled`` check, returning a shared
+no-op singleton; nothing is allocated per call.  ``begin`` (ended from
+any thread), ``add_span`` and ``event`` stay tracer-only: a profiler
+annotation must end on the thread that began it.
 """
 from __future__ import annotations
 
@@ -28,6 +36,26 @@ import itertools
 import json
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+#: True while a profiler session collects host annotations (a static
+#: check on the profiler's TraceMe, tens of nanoseconds)
+profiling = TraceAnnotation.is_enabled
+
+
+def _profiler_args(args: dict) -> dict:
+    """The span args the profiler can carry: numbers, and strings free of
+    the characters its annotation encoding reserves."""
+    return {k: v for k, v in args.items()
+            if isinstance(v, (bool, int, float))
+            or (isinstance(v, str) and not any(c in v for c in "#,="))}
+
+
+def _annotation(name: str, args: dict):
+    ann = TraceAnnotation(name, **_profiler_args(args))
+    ann.__enter__()
+    return ann
 
 
 class _NoopSpan:
@@ -48,15 +76,51 @@ class _NoopSpan:
     def end(self, t=None):
         return self
 
+    def drop(self):
+        return self
+
 
 NOOP_SPAN = _NoopSpan()
 
 
+class _ProfilerSpan:
+    """A span only the profiler sees: the tracer is disabled while a
+    profiler session runs."""
+
+    __slots__ = ("_ann",)
+    span_id = None
+
+    def __init__(self, name: str, args: dict):
+        self._ann = _annotation(name, args)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+    def set(self, **kw):
+        if self._ann is not None:
+            self._ann.set_metadata(**_profiler_args(kw))
+        return self
+
+    def end(self, t=None):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        return self
+
+    def drop(self):
+        return self
+
+
 class Span:
     __slots__ = ("name", "cat", "span_id", "parent_id", "t0", "t1",
-                 "tid", "args", "_tracer", "_on_stack")
+                 "tid", "args", "_tracer", "_on_stack", "_ann", "_dropped")
 
-    def __init__(self, tracer, name, cat, span_id, parent_id, t0, tid, args):
+    def __init__(self, tracer, name, cat, span_id, parent_id, t0, tid, args,
+                 ann=None):
         self.name = name
         self.cat = cat
         self.span_id = span_id
@@ -67,14 +131,28 @@ class Span:
         self.args = args
         self._tracer = tracer
         self._on_stack = False
+        self._ann = ann
+        self._dropped = False
 
     def set(self, **kw):
         self.args.update(kw)
+        if self._ann is not None:
+            self._ann.set_metadata(**_profiler_args(kw))
         return self
 
     def end(self, t: float | None = None):
         if self.t1 is None:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
             self._tracer._finish(self, t)
+        return self
+
+    def drop(self):
+        """Keep this span out of the tracer's records when it ends (the
+        profiler still sees its annotation): for a wait that produced
+        nothing worth a record."""
+        self._dropped = True
         return self
 
     def __enter__(self):
@@ -140,14 +218,16 @@ class Tracer:
     def span(self, name: str, cat: str = "", parent: int | None = None,
              **args):
         """Live nested span (context manager).  Parent defaults to the
-        enclosing live span on this thread."""
+        enclosing live span on this thread.  While a profiler session
+        runs, the span is also a ``TraceAnnotation`` of the same name."""
         if not self._enabled:
-            return NOOP_SPAN
+            return _ProfilerSpan(name, args) if profiling() else NOOP_SPAN
         st = self._stack()
         pid = parent if parent is not None else (
             st[-1].span_id if st else None)
         sp = Span(self, name, cat, next(self._ids), pid, self.now(),
-                  threading.get_ident(), args)
+                  threading.get_ident(), args,
+                  _annotation(name, args) if profiling() else None)
         sp._on_stack = True
         st.append(sp)
         return sp
@@ -171,6 +251,8 @@ class Tracer:
                     st.pop()
                 if st:
                     st.pop()
+        if sp._dropped:
+            return
         self._append({"ph": "X", "name": sp.name, "cat": sp.cat,
                       "id": sp.span_id, "parent": sp.parent_id,
                       "t0": sp.t0, "t1": sp.t1, "tid": sp.tid,
@@ -221,12 +303,13 @@ class Tracer:
         with self._lock:
             return len(self._records)
 
-    def export_chrome(self) -> dict:
+    def export_chrome(self, extra=()) -> dict:
         """Chrome trace-event JSON (Perfetto-loadable): complete (``X``)
         events with microsecond ``ts``/``dur``; ``args`` carries the
-        explicit ``span_id``/``parent_id`` links."""
+        explicit ``span_id``/``parent_id`` links.  ``extra`` adds records
+        of the same shape kept outside the tracer."""
         events = []
-        for r in self.records():
+        for r in [*self.records(), *extra]:
             args = {"span_id": r["id"], "parent_id": r["parent"], **r["args"]}
             ev = {"name": r["name"], "cat": r["cat"] or "default",
                   "pid": 1, "tid": int(r["tid"]) & 0x7FFFFFFF,
@@ -250,6 +333,13 @@ class Tracer:
 NOOP_TRACER = Tracer(enabled=False)
 
 _GLOBAL = NOOP_TRACER
+
+
+def span(name: str, cat: str = "", **args):
+    """A live span for code that holds no tracer: recorded by the
+    process-global tracer when one is installed (``set_tracer``), and a
+    profiler annotation whenever a profiler session runs."""
+    return _GLOBAL.span(name, cat, **args)
 
 
 def get_tracer() -> Tracer:

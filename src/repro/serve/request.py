@@ -4,7 +4,8 @@ A :class:`ServeRequest` is what ``PipelineServer.submit`` hands back: a
 single-query slice of the Q relation plus a completion event the caller
 waits on.  Every request carries a :class:`RequestTrace` — the structured
 per-request accounting (queue wait, batch size, bucket, cache hit depth,
-per-stage wall-clock) that ``server.stats()`` aggregates.
+per-stage wall-clock, its batch's phases and padded work) that
+``server.stats()`` aggregates.
 """
 from __future__ import annotations
 
@@ -58,6 +59,15 @@ class RequestTrace:
     tenant: str = ""                # pipeline (tenant) it executed under
     cross_prefix_hit: bool = False  # cache hit written by another pipeline
     stage_ms: tuple = ()            # ((stage label, ms), ...) of its batch
+    #: ((phase, ms), ...) of its batch from close to its reply, which tile
+    #: ``service_ms``: cache_lookup, assemble, stage:<label>,
+    #: device_wait:<label>, cache_store, reply (handoff for a generate
+    #: stage's requests, whose decode follows)
+    phase_ms: tuple = ()
+    #: its batch's delta of the engine's padded-work counters (rows_live,
+    #: rows_pad, slots_live, slots_pad), one dict shared by the batch's
+    #: requests; valid while one serving thread dispatches on the engine
+    work: dict = dataclasses.field(default_factory=dict)
     # -- decode (generate-stage requests only; zero otherwise) --------------
     ttft_ms: float = 0.0            # submit -> first generated token
     n_tokens: int = 0               # tokens decoded for this request

@@ -90,6 +90,7 @@ class Batch:
     reason: str          # "full" | "deadline" | "drain"
     t_closed: float
     shed: list = dataclasses.field(default_factory=list)   # dropped pre-exec
+    info: dict = dataclasses.field(default_factory=dict)   # close decision
 
 
 class _Lane:
@@ -458,17 +459,17 @@ class MicroBatchScheduler:
         self._batch_close.inc(1, (reason,))
         rung = select_ladder_bucket(self.ladder, max(len(live), 1),
                                     clamp=True)
+        s_rung_ms = _ms(self._bucket_est(rung))
         if self.recorder is not None:
             self.recorder.record(
                 "batch_close", reason=reason, size=len(live), rung=rung,
                 shed=len(shed), cap=cap, queued_after=self._n_queued,
-                s_rung_ms=_ms(self._bucket_est(rung)))
-        self.tracer.event(
-            "sched.batch_close", "sched", reason=reason, size=len(live),
-            rung=rung, shed=len(shed), cap=cap,
-            s_rung_ms=_ms(self._bucket_est(rung)),
-            slot_ms=_ms(self._slot_ewma))
-        return Batch(requests=live, reason=reason, t_closed=now, shed=shed)
+                s_rung_ms=s_rung_ms)
+        return Batch(requests=live, reason=reason, t_closed=now, shed=shed,
+                     info={"reason": reason, "size": len(live), "rung": rung,
+                           "shed": len(shed), "cap": cap,
+                           "s_rung_ms": s_rung_ms,
+                           "slot_ms": _ms(self._slot_ewma)})
 
     def next_batch(self, *, block: bool = False, timeout: float | None = None,
                    drain: bool = False) -> Batch | None:
@@ -478,7 +479,22 @@ class MicroBatchScheduler:
         ``timeout`` elapses).  ``drain=True`` closes a batch from whatever
         is queued immediately — the synchronous replay/test mode.  A batch
         that shed its every candidate (all deadlines infeasible) is still
-        returned — the server must fail the shed requests' waiters."""
+        returned — the server must fail the shed requests' waiters.
+
+        The call is a ``serve.batch_wait`` span whose args are the close
+        decision (reason, size, rung, sheds, cap and the service-model
+        state it used); a wait that closes no batch reaches the profiler
+        only, not the tracer's records."""
+        with self.tracer.span("serve.batch_wait", "serve") as sp:
+            batch = self._wait_batch(block, timeout, drain)
+            if batch is None:
+                sp.drop()
+            else:
+                sp.set(**batch.info)
+        return batch
+
+    def _wait_batch(self, block: bool, timeout: float | None,
+                    drain: bool) -> Batch | None:
         t_give_up = None if timeout is None else time.monotonic() + timeout
         with self._cv:
             while True:

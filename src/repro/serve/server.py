@@ -44,6 +44,7 @@ synchronously with :meth:`pump`.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import threading
@@ -55,6 +56,7 @@ import numpy as np
 
 from repro.core import ir
 from repro.obs import NOOP_TRACER, FlightRecorder, MetricsRegistry, Tracer
+from repro.obs import hostpause
 from repro.core.compiler import Context, _execute
 from repro.core.passes import compile_pipeline
 from repro.core.plan import chain_prefix_digests
@@ -74,6 +76,43 @@ _FALLBACK_LADDER = (1, 2, 4, 8, 16)
 #: sentinel distinguishing "caller said nothing" (inherit the server
 #: default) from an explicit ``timeout_ms=None`` ("no deadline")
 _UNSET = object()
+
+
+class _BatchTimeline:
+    """One micro-batch from its close to each reply, in phases.
+
+    Each phase is a live ``serve.<phase>`` span (a profiler annotation
+    while a profiler session runs) and a duration on the server's clock.
+    A phase runs from the end of the one before, the first from the batch
+    close, so the durations tile the interval: a request's ``phase_ms``
+    is every phase before its reply plus its own share of the reply, and
+    sums to its ``service_ms``.  ``work`` is the batch's delta of the
+    backend's padded-work counters (``counts``, None without an engine),
+    one dict shared by the batch's requests, brought up to date at each
+    reply."""
+
+    __slots__ = ("tracer", "t", "phases", "counts", "work", "_w0")
+
+    def __init__(self, tracer, t_closed: float, counts):
+        self.tracer, self.t, self.phases = tracer, t_closed, []
+        self.counts, self.work = counts, {}
+        self._w0 = None if counts is None else counts()
+
+    @contextlib.contextmanager
+    def phase(self, name: str, **args):
+        with self.tracer.span(f"serve.{name}", "serve", **args) as sp:
+            yield sp
+        t = time.monotonic()
+        self.phases.append((name, 1000.0 * (t - self.t)))
+        self.t = t
+
+    def upto(self, t: float, name: str = "reply") -> tuple:
+        """The phases of a request answered at ``t``, inside phase
+        ``name``; brings ``work`` up to date."""
+        if self.counts is not None:
+            w1 = self.counts()
+            self.work.update({k: w1[k] - self._w0[k] for k in w1})
+        return tuple(self.phases) + ((name, 1000.0 * (t - self.t)),)
 
 
 @dataclasses.dataclass
@@ -120,6 +159,8 @@ class PipelineServer:
         # here; tracer/recorder are the opt-in layers (ServeConfig
         # .with_observability) and default to shared no-ops
         self.metrics = MetricsRegistry()
+        # the process's collector pauses, from the watch start() installs
+        hostpause.register(self.metrics)
         self.tracer = (Tracer(enabled=True, capacity=cfg.obs_trace_events)
                        if cfg.obs_tracing else NOOP_TRACER)
         self.recorder = (FlightRecorder(cfg.obs_recorder_events)
@@ -250,6 +291,10 @@ class PipelineServer:
             return self._rid
 
     def _make_requests(self, Q, timeout_ms, lane, pipeline) -> list:
+        with self.tracer.span("serve.submit", "serve"):
+            return self._admit(Q, timeout_ms, lane, pipeline)
+
+    def _admit(self, Q, timeout_ms, lane, pipeline) -> list:
         tenant = self._tenant(pipeline)
         lane = self.config.default_lane if lane is None else lane
         nq = int(np.asarray(Q["qid"]).shape[0])
@@ -349,7 +394,10 @@ class PipelineServer:
                 return total
 
     def start(self) -> "PipelineServer":
-        """Spawn the serving thread (continuous mode)."""
+        """Spawn the serving thread (continuous mode) and watch the
+        process's garbage collections (:mod:`repro.obs.hostpause`) while
+        it runs."""
+        hostpause.install(self)
         if self._thread is None:
             self._stop = False
             self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -363,6 +411,7 @@ class PipelineServer:
             self._thread.join()
             self._thread = None
         self.pump()                      # never strand queued requests
+        hostpause.remove(self)
 
     def _loop(self) -> None:
         while not self._stop:
@@ -427,6 +476,26 @@ class PipelineServer:
     # -- batch execution ----------------------------------------------------
     def _execute_batch(self, batch) -> None:
         now = batch.t_closed
+        tl = _BatchTimeline(self.tracer, now,
+                            None if self.engine is None
+                            else self.backend.work_counts)
+        with self.tracer.span("serve.batch", "serve",
+                              reason=batch.reason) as sp:
+            # the close's bookkeeping: queue waits stamped, shed and
+            # expired requests answered; a batch left with no live request
+            # leaves no record
+            with tl.phase("close") as ph:
+                live = self._close_batch(batch, now)
+                if not live:
+                    ph.drop()
+                    sp.drop()
+            if not live:
+                return
+            self._run_batch(live, tl, sp)
+
+    def _close_batch(self, batch, now: float) -> list:
+        """Stamp the batch's requests at its close; answer those shed or
+        expired while queued, and return the live ones."""
         for req in batch.shed:          # shed pre-execution by the scheduler
             req.trace.t_scheduled = now
             req.trace.queue_wait_ms = 1000.0 * (now - req.t_enqueued)
@@ -442,37 +511,45 @@ class PipelineServer:
                 self._finish(req, None, timed_out=True)
             else:
                 live.append(req)
-        if not live:
-            return
-        self.log.record_batch(len(live))
+        if live:
+            self.log.record_batch(len(live))
+        return live
+
+    def _run_batch(self, live, tl: _BatchTimeline, sp) -> None:
         t_exec0 = time.monotonic()
-        # deepest cached prefix per request, then group by (tenant, resume
-        # depth) so each group executes its remaining suffix as one
-        # micro-batch of its own pipeline
+        sp.set(size=len(live))
+        for req in live:
+            req.trace.work = tl.work
+        # deepest cached prefix per request, then group by (tenant,
+        # resume depth) so each group executes its remaining suffix as
+        # one micro-batch of its own pipeline
         groups: dict[tuple, list] = {}
         cached: dict[int, tuple] = {}
         max_bucket = 0
-        for req in live:
-            tenant = self._tenants[req.tenant]
-            depth, val, writer = self.cache.lookup_deepest(
-                self._prefix_digests(tenant), req.qdigest,
-                reader=tenant.name)
-            req.trace.cache_hit_depth = depth
-            req.trace.cross_prefix_hit = (depth > 0 and writer is not None
-                                          and writer != tenant.name)
-            cached[req.rid] = val
-            groups.setdefault((req.tenant, depth), []).append(req)
+        with tl.phase("cache_lookup"):
+            for req in live:
+                tenant = self._tenants[req.tenant]
+                depth, val, writer = self.cache.lookup_deepest(
+                    self._prefix_digests(tenant), req.qdigest,
+                    reader=tenant.name)
+                req.trace.cache_hit_depth = depth
+                req.trace.cross_prefix_hit = (
+                    depth > 0 and writer is not None
+                    and writer != tenant.name)
+                cached[req.rid] = val
+                groups.setdefault((req.tenant, depth), []).append(req)
         for tname, depth in sorted(groups, key=lambda g: (g[0], -g[1])):
             grp = groups[(tname, depth)]
             try:
                 bucket = self._run_group(self._tenants[tname], grp, depth,
-                                         [cached[r.rid] for r in grp])
+                                         [cached[r.rid] for r in grp], tl)
                 max_bucket = max(max_bucket, bucket)
             except BaseException as e:
                 self.last_error = e
                 for req in grp:
                     req.error = e
                     self._finish(req, None)
+        sp.set(bucket=max_bucket)
         # service-time feedback: the per-bucket/per-slot EWMAs of these are
         # the scheduler's S in every shed decision and its deadline cap on
         # batch packing; the engine keeps its own per-bucket view
@@ -482,101 +559,140 @@ class PipelineServer:
             self.engine.note_service_time(max_bucket, dt)
 
     def _run_group(self, tenant: _Tenant, reqs, depth: int,
-                   cached_vals) -> int:
+                   cached_vals, tl: _BatchTimeline) -> int:
         """Execute one (tenant, resume-depth) group as a padded micro-batch;
-        returns the ladder bucket it padded to (0 = pure cache replay)."""
+        returns the ladder bucket it padded to (0 = pure cache replay).
+
+        Every place the serving thread waits for the device is a
+        ``device_wait:<label>`` phase, named for the last stage dispatched:
+        the per-stage barrier of ``trace_stages``, the wait before an
+        intermediate stage's results are copied to the host for the cache,
+        and the wait for the final results."""
         chain, prefixes = tenant.chain, self._prefix_digests(tenant)
         L = len(chain)
         qids = [r.qid for r in reqs]
         if depth >= L:                       # full-pipeline cache hits
-            for req, (Qc, Rc) in zip(reqs, cached_vals):
-                Qr, Rr = StageResultCache.restamp_qids(Qc, Rc, [req.qid])
-                # row(…, 0) copies: the served result must never alias the
-                # live cache entry (same invariant as the miss path)
-                self._finish(req, StageResultCache.row(
-                    Rr if Rr is not None else Qr, 0))
+            with tl.phase("reply"):
+                for req, (Qc, Rc) in zip(reqs, cached_vals):
+                    Qr, Rr = StageResultCache.restamp_qids(Qc, Rc, [req.qid])
+                    # row(…, 0) copies: the served result must never alias
+                    # the live cache entry (same invariant as the miss path)
+                    self._reply(req, StageResultCache.row(
+                        Rr if Rr is not None else Qr, 0), tl)
             return 0
-        if depth == 0:
-            Q = StageResultCache.stack_rows([r.Q for r in reqs])
-            R = None
-        else:                                # resume mid-chain
-            Q = StageResultCache.stack_rows([v[0] for v in cached_vals])
-            R_rows = [v[1] for v in cached_vals]
-            R = (None if R_rows[0] is None
-                 else StageResultCache.stack_rows(R_rows))
-            Q, R = StageResultCache.restamp_qids(Q, R, qids)
         n = len(reqs)
-        bucket = (self.engine.select_bucket(n) if self.engine is not None
-                  else self.scheduler.select_bucket(n))
-        for req in reqs:
-            req.trace.bucket = bucket
-        # pad up to the bucket BEFORE execution: every stage then sees
-        # exactly the ladder shapes warm-up compiled (no per-size variants
-        # anywhere, eager pre-steps included); padded rows are dropped when
-        # results are sliced per request below
-        Q = StageResultCache.pad_rows(Q, bucket - n)
-        R = StageResultCache.pad_rows(R, bucket - n)
-        ctx = Context(self.backend)
-        tok = ctx.source_token(Q, R)
+        with tl.phase("assemble", n=n):
+            if depth == 0:
+                Q = StageResultCache.stack_rows([r.Q for r in reqs])
+                R = None
+            else:                            # resume mid-chain
+                Q = StageResultCache.stack_rows([v[0] for v in cached_vals])
+                R_rows = [v[1] for v in cached_vals]
+                R = (None if R_rows[0] is None
+                     else StageResultCache.stack_rows(R_rows))
+                Q, R = StageResultCache.restamp_qids(Q, R, qids)
+            bucket = (self.engine.select_bucket(n) if self.engine is not None
+                      else self.scheduler.select_bucket(n))
+            for req in reqs:
+                req.trace.bucket = bucket
+            # pad up to the bucket BEFORE execution: every stage then sees
+            # exactly the ladder shapes warm-up compiled (no per-size
+            # variants anywhere, eager pre-steps included); padded rows are
+            # dropped when results are sliced per request below
+            Q = StageResultCache.pad_rows(Q, bucket - n)
+            R = StageResultCache.pad_rows(R, bucket - n)
+            ctx = Context(self.backend)
+            tok = ctx.source_token(Q, R)
         stage_times = []
         # a generate tenant runs only its retrieval prefix here; the final
         # stage is decode, which the request rides iteration-level in the
         # tenant's pool (handoff below) instead of run-to-completion
         L_here = L - 1 if tenant.generate is not None else L
-        for i in range(depth, L_here):
-            stage = chain[i]
-            t0 = time.monotonic() if self.trace_stages else 0.0
-            Q, R, tok = _execute(stage, ctx, Q, R, tok)
-            if self.trace_stages:
-                jax.block_until_ready((Q, R))
-                ms = 1000.0 * (time.monotonic() - t0)
+        synced = True
+        # the engine counts rows past the first n as padding
+        live_rows = (self.engine.live_rows(n) if self.engine is not None
+                     else contextlib.nullcontext())
+        with live_rows:
+            for i in range(depth, L_here):
+                stage = chain[i]
                 label = stage.label()
-                stage_times.append((label, round(ms, 3)))
-                self.log.record_stage(label, ms)
-            if self.cache_stages and self.cache.enabled and i < L - 1:
-                # one device->host conversion per stage, rows sliced from
-                # the host copy (per-row device slicing would compile a
-                # tiny XLA program per (arity, index) — a latency storm)
-                Qh = StageResultCache.to_host(Q)
-                Rh = None if R is None else StageResultCache.to_host(R)
-                for j, req in enumerate(reqs):
-                    self.cache.store(prefixes[i], req.qdigest,
-                                     StageResultCache.row(Qh, j),
-                                     None if Rh is None
-                                     else StageResultCache.row(Rh, j),
-                                     writer=tenant.name)
-        if tenant.generate is not None:
-            # answer boundary: assemble each live row's prompt (batched at
-            # the same bucket shape warm-up compiled) and queue it for a
-            # decode slot — these requests retire from _decode_pump, and
-            # the batch they just rode mixed with pure-retrieval tenants
-            gen = tenant.generate
-            prompts = gen.assemble(ctx, Q, R)
-            jax.block_until_ready(prompts)
-            prompts = np.asarray(prompts)
+                t0 = time.monotonic()
+                with tl.phase(f"stage:{label}"):
+                    Q, R, tok = _execute(stage, ctx, Q, R, tok)
+                synced = False
+                if self.trace_stages:
+                    with tl.phase(f"device_wait:{label}"):
+                        jax.block_until_ready((Q, R))
+                    synced = True
+                    ms = 1000.0 * (time.monotonic() - t0)
+                    stage_times.append((label, round(ms, 3)))
+                    self.log.record_stage(label, ms)
+                if self.cache_stages and self.cache.enabled and i < L - 1:
+                    if not synced:
+                        with tl.phase(f"device_wait:{label}"):
+                            jax.block_until_ready((Q, R))
+                        synced = True
+                    # one device->host conversion per stage, rows sliced
+                    # from the host copy (per-row device slicing would
+                    # compile a tiny XLA program per (arity, index) — a
+                    # latency storm)
+                    with tl.phase("cache_store"):
+                        Qh = StageResultCache.to_host(Q)
+                        Rh = None if R is None else StageResultCache.to_host(R)
+                        for j, req in enumerate(reqs):
+                            self.cache.store(prefixes[i], req.qdigest,
+                                             StageResultCache.row(Qh, j),
+                                             None if Rh is None
+                                             else StageResultCache.row(Rh, j),
+                                             writer=tenant.name)
+            if tenant.generate is not None:
+                # answer boundary: assemble each live row's prompt (batched
+                # at the same bucket shape warm-up compiled) and queue it
+                # for a decode slot — these requests retire from
+                # _decode_pump, and the batch they just rode mixed with
+                # pure-retrieval tenants
+                gen, label = tenant.generate, chain[-1].label()
+                with tl.phase(f"stage:{label}"):
+                    prompts = gen.assemble(ctx, Q, R)
+                with tl.phase(f"device_wait:{label}"):
+                    jax.block_until_ready(prompts)
+                with tl.phase("handoff"):
+                    prompts = np.asarray(prompts)
+                    Qh = StageResultCache.to_host(Q)
+                    Rh = StageResultCache.to_host(R)
+                    for j, req in enumerate(reqs):
+                        req.trace.stage_ms = tuple(stage_times)
+                        req.trace.phase_ms = tl.upto(time.monotonic(),
+                                                     "handoff")
+                        req._prompt = prompts[j]
+                        req._Q_row = StageResultCache.row(Qh, j)
+                        req._R_row = StageResultCache.row(Rh, j)
+                        self.scheduler.decode_submit(req)
+                return bucket
+        if not synced:
+            with tl.phase(f"device_wait:{label}"):
+                jax.block_until_ready((Q, R))
+        # the final results to the host, then row by row: the row's cache
+        # entry, then its reply
+        with tl.phase("reply"):
             Qh = StageResultCache.to_host(Q)
-            Rh = StageResultCache.to_host(R)
+            Rh = None if R is None else StageResultCache.to_host(R)
+            result = Rh if Rh is not None else Qh
             for j, req in enumerate(reqs):
                 req.trace.stage_ms = tuple(stage_times)
-                req._prompt = prompts[j]
-                req._Q_row = StageResultCache.row(Qh, j)
-                req._R_row = StageResultCache.row(Rh, j)
-                self.scheduler.decode_submit(req)
-            return bucket
-        jax.block_until_ready((Q, R))
-        Qh = StageResultCache.to_host(Q)
-        Rh = None if R is None else StageResultCache.to_host(R)
-        result = Rh if Rh is not None else Qh
-        for j, req in enumerate(reqs):
-            req.trace.stage_ms = tuple(stage_times)
-            if self.cache.enabled:
-                self.cache.store(
-                    prefixes[L - 1], req.qdigest,
-                    StageResultCache.row(Qh, j),
-                    None if Rh is None else StageResultCache.row(Rh, j),
-                    writer=tenant.name)
-            self._finish(req, StageResultCache.row(result, j))
+                if self.cache.enabled:
+                    self.cache.store(
+                        prefixes[L - 1], req.qdigest,
+                        StageResultCache.row(Qh, j),
+                        None if Rh is None else StageResultCache.row(Rh, j),
+                        writer=tenant.name)
+                self._reply(req, StageResultCache.row(result, j), tl)
         return bucket
+
+    def _reply(self, req, result, tl: _BatchTimeline) -> None:
+        t = time.monotonic()
+        req.trace.phase_ms = tl.upto(t)
+        self._finish(req, result, t=t)
 
     def _decode_pump(self) -> int:
         """One iteration of every decode pool: admit queued prompts into
@@ -626,8 +742,9 @@ class PipelineServer:
                 retired += 1
         return retired
 
-    def _finish(self, req, result, *, timed_out: bool = False) -> None:
-        t = time.monotonic()
+    def _finish(self, req, result, *, timed_out: bool = False,
+                t: float | None = None) -> None:
+        t = time.monotonic() if t is None else t
         tr = req.trace
         tr.t_done = t
         tr.timed_out = timed_out
@@ -650,11 +767,13 @@ class PipelineServer:
 
     def _emit_request_spans(self, tr) -> None:
         """Retrospective per-request lifecycle spans, emitted at finish
-        from the ``RequestTrace`` timestamps.  Spans link by explicit
-        parent id (nesting is data, not wall-clock containment), so a
-        request admitted on the caller thread and executed on the serving
-        thread still exports as one nested tree; each request gets its
-        own synthetic Perfetto track (``tid = rid``)."""
+        from the ``RequestTrace`` timestamps (tracer only: they never
+        reach the profiler).  Spans link by explicit parent id (nesting is
+        data, not wall-clock containment), so a request admitted on the
+        caller thread and executed on the serving thread still exports as
+        one nested tree; each request gets its own synthetic Perfetto
+        track (``tid = rid``).  The batch's phases are live spans on the
+        serving thread, and ``RequestTrace.phase_ms`` holds them."""
         tracer, rel, tid = self.tracer, self.tracer.rel, tr.rid
         outcome = ("errors" if tr.errored else "shed" if tr.shed
                    else "timed_out" if tr.timed_out
@@ -671,19 +790,13 @@ class PipelineServer:
         # decode start = first generated token; before it, the request
         # was riding its retrieval micro-batch
         t_dec0 = (tr.t_arrival + tr.ttft_ms / 1000.0 if tr.ttft_ms else None)
-        batch = tracer.add_span(
+        tracer.add_span(
             "serve.batch", rel(tr.t_scheduled),
             rel(t_dec0 if t_dec0 is not None else tr.t_done), cat="serve",
             parent=root, tid=tid, reason=tr.batch_reason,
             batch_size=tr.batch_size, bucket=tr.bucket,
             cache_hit_depth=tr.cache_hit_depth,
             cross_prefix_hit=tr.cross_prefix_hit)
-        t = tr.t_scheduled            # stage stamps are durations only:
-        for label, ms in tr.stage_ms:  # lay them end-to-end from close
-            tracer.add_span(f"serve.stage:{label}", rel(t),
-                            rel(t + ms / 1000.0), cat="serve",
-                            parent=batch, tid=tid, ms=ms)
-            t += ms / 1000.0
         if t_dec0 is not None:
             tracer.add_span("serve.decode", rel(t_dec0), rel(tr.t_done),
                             cat="serve", parent=root, tid=tid,
@@ -693,13 +806,17 @@ class PipelineServer:
     # -- observability ------------------------------------------------------
     def trace_export(self, path: str | None = None) -> dict:
         """Chrome trace-event JSON of every retained span (request
-        lifecycles, scheduler batch closes, engine dispatches and
+        lifecycles; the serving thread's batch waits and batches with
+        their phases; submits, engine dispatches, collector pauses and
         cause-tagged jit compiles).  Load the written file in Perfetto
-        (https://ui.perfetto.dev) to see per-request tracks with nested
-        queue/batch/stage/decode children.  Requires
+        (https://ui.perfetto.dev) to see per-request tracks with
+        queue/batch/decode children beside the serving thread's live
+        ``serve.batch`` spans.  Requires
         ``ServeConfig.with_observability()``; disabled tracing exports an
-        empty event list."""
-        out = self.tracer.export_chrome()
+        empty event list (the live spans still reach a running
+        ``jax.profiler`` session)."""
+        out = self.tracer.export_chrome(
+            hostpause.records(self.tracer) if self.tracer.enabled else ())
         if path is not None:
             with open(path, "w") as f:
                 json.dump(out, f)
