@@ -181,6 +181,24 @@ def topics(query: dict, n: int, seed: int, rank_to_term: np.ndarray,
             "weights": weights}
 
 
+#: salt of block b >= 1 of a topic pool is ``BLOCK_SALT + b``: far from
+#: the salts of ``topics`` (1), ``arrivals`` (2) and ``knee.py`` (50, 60+)
+BLOCK_SALT = 1 << 20
+
+
+def topic_blocks(query: dict, pool: int, n_blocks: int, seed: int,
+                 rank_to_term: np.ndarray) -> dict:
+    """``n_blocks`` blocks of ``pool`` topics, one after the other.
+    Block 0 is ``topics(query, pool, seed, rank_to_term)`` bit for bit;
+    block b >= 1 is drawn alike with salt ``BLOCK_SALT + b``.  ``qid``s
+    run on across blocks: block b holds ``b * pool + arange(pool)``."""
+    blocks = [topics(query, pool, seed, rank_to_term, salt=1 if b == 0
+                     else BLOCK_SALT + b) for b in range(n_blocks)]
+    return {"qid": np.arange(n_blocks * pool, dtype=np.int32),
+            **{k: np.concatenate([blk[k] for blk in blocks])
+               for k in ("terms", "weights")}}
+
+
 def empty_topics(n: int) -> dict:
     """``n`` topics without terms: the shapes of a batch, for warming up."""
     return {"qid": np.arange(n, dtype=np.int32),

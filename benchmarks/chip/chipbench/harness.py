@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import math
 import os
 import shutil
 import sys
@@ -86,6 +87,17 @@ class Traffic:
     warm: dict | None = None          # warm-up topics (no terms)
 
 
+def pool_blocks(traffic: dict, seconds: float) -> int:
+    """Blocks of ``pool`` topics a closed loop needs to keep its clients
+    sending fresh topics at ``rate_ceiling_qps`` for ``seconds``; one
+    where the traffic states no ceiling."""
+    ceiling = traffic.get("rate_ceiling_qps")
+    if ceiling is None:
+        return 1
+    return math.ceil((int(traffic["clients"]) + float(ceiling) * seconds)
+                     / int(traffic["pool"]))
+
+
 def make_traffic(traffic: dict, seconds: float, seed: int,
                  rank_to_term: np.ndarray) -> Traffic:
     q, mode = traffic["query"], traffic["mode"]
@@ -96,7 +108,9 @@ def make_traffic(traffic: dict, seconds: float, seed: int,
         out.Q = datagen.topics(q, len(out.due), seed, rank_to_term)
         out.warm = datagen.empty_topics(1)
     elif mode == "closed":
-        out.Q = datagen.topics(q, int(traffic["pool"]), seed, rank_to_term)
+        out.Q = datagen.topic_blocks(q, int(traffic["pool"]),
+                                     pool_blocks(traffic, seconds), seed,
+                                     rank_to_term)
         out.warm = datagen.empty_topics(1)
     else:
         raise spec.SpecError(f"unknown traffic mode {mode!r}")
@@ -119,7 +133,8 @@ def drive(sys_, traffic: dict, tr: Traffic, seconds: float,
     if traffic["mode"] == "open":
         return loadgen.open_loop(sys_.server, tr.Q, tr.due, seconds, ann)
     return loadgen.closed_loop(sys_.server, tr.Q, int(traffic["clients"]),
-                               seconds, ann)
+                               seconds, ann,
+                               traffic.get("rate_ceiling_qps"))
 
 
 @dataclasses.dataclass

@@ -102,11 +102,16 @@ def open_loop(server, Q: dict, due: np.ndarray, seconds: float,
 
 
 def closed_loop(server, Q: dict, clients: int, seconds: float,
-                ann: Annotator) -> Window:
+                ann: Annotator,
+                rate_ceiling_qps: float | None = None) -> Window:
     """``clients`` callers, each sending its next query when the previous
     one is answered, for ``seconds``; then no caller sends again, and the
     window ends when the last answer is in (served batches take seconds,
-    so a window cut at a fixed time would count whole batches or none)."""
+    so a window cut at a fixed time would count whole batches or none).
+
+    Every request is a fresh topic of ``Q``, which is sized for
+    ``rate_ceiling_qps``: a loop that runs out of topics has answered
+    faster than that, and raises rather than report a result."""
     n_pool = int(Q["qid"].shape[0])
     sent, late, live = [], [], []
     nxt = 0
@@ -114,8 +119,13 @@ def closed_loop(server, Q: dict, clients: int, seconds: float,
     def send(t):
         nonlocal nxt
         if nxt >= n_pool:
-            raise RuntimeError(f"closed loop used all {n_pool} topics of "
-                               f"its pool; raise the traffic's pool size")
+            rate = nxt / max(time.monotonic() - t0, 1e-9)
+            ceiling = ("no" if rate_ceiling_qps is None
+                       else f"a {rate_ceiling_qps} q/s")
+            raise RuntimeError(
+                f"closed loop used all {n_pool} topics of its pool at "
+                f"{rate:.1f} q/s, drawn for {ceiling} rate ceiling; "
+                f"raise the traffic's rate_ceiling_qps")
         req = _submit(server, datagen.rows(Q, nxt, nxt + 1), ann)
         sent.append((nxt, t, req))
         late.append(0.0)

@@ -94,6 +94,11 @@ def run(cell, args, device, t_start, H, system, jax) -> int:
           f"{system.gate_decisions(sys_)}")
     tr = H.make_traffic(cell.traffic, seconds, args.seed,
                         sys_.coll.rank_to_term)
+    if cell.traffic["mode"] == "closed":
+        H.log(f"[setup] topic pool {len(tr.Q['qid'])} "
+              f"({H.pool_blocks(cell.traffic, seconds)} blocks of "
+              f"{cell.traffic['pool']}), rate ceiling "
+              f"{cell.traffic.get('rate_ceiling_qps')} q/s")
     warm = H.warm_up(sys_, tr)
     H.log(f"[setup] warm-up {warm}")
     ann = loadgen.Annotator(trace)
