@@ -8,10 +8,11 @@ those lists, so the tests can check it on a few hand-made events.
 
 * busy     union of the intervals of the operations on a device's
            "XLA Ops" line, clipped to the window; averaged over devices.
-* idle gap an interval of the window in which no operation ran, named by
-           the host span (the benchmark's own ``bench.*`` annotations)
-           that overlaps it most; spans that only wait (``bench.sleep``)
-           name a gap only where no working span does.
+* idle gap an interval of the window in which no operation ran; the
+           longest are named by the host span (the benchmark's own
+           ``bench.*`` annotations) that overlaps each most; spans that
+           only wait (``bench.sleep``) name a gap only where no working
+           span does.
 * kernel   the summed device time of the events whose name contains the
            kernel's name.
 """
@@ -156,6 +157,7 @@ class Summary:
     idle_gaps: list               # [(name, seconds)] longest first
     device_ops: list              # [(name, seconds)] most time first
     kernel_events: dict           # kernel name -> [Event] in the window
+    n_gaps: int                   # idle gaps in the window, named or not
 
     @property
     def idle_share(self) -> float:
@@ -169,8 +171,12 @@ def summarize(trace: Trace, kernels=(), top: int = 10) -> Summary:
     busy = [busy_ns(evs, lo, hi) for evs in trace.device_ops.values()]
     first = sorted(trace.device_ops)[0]
     evs = trace.device_ops[first]
-    idle = sorted(((name_gap(g, trace.host_spans), (g[1] - g[0]) * 1e-9)
-                   for g in gaps(evs, lo, hi)), key=lambda x: -x[1])
+    # Only the ``top`` longest gaps are named: naming scans every span, and
+    # a fast server's window holds thousands of gaps and tens of thousands
+    # of spans.  The stable sort by length alone keeps the order of ties.
+    idle = sorted(((g, (g[1] - g[0]) * 1e-9) for g in gaps(evs, lo, hi)),
+                  key=lambda x: -x[1])
+    named = [(name_gap(g, trace.host_spans), s) for g, s in idle[:top]]
     per_op: dict = {}
     for dev in trace.device_ops.values():
         for ev in dev:
@@ -186,5 +192,5 @@ def summarize(trace: Trace, kernels=(), top: int = 10) -> Summary:
            for k in kernels}
     return Summary(window_s=(hi - lo) * 1e-9,
                    busy_s=sum(busy) / n_dev * 1e-9,
-                   idle_gaps=idle[:top], device_ops=ops[:top],
-                   kernel_events=kev)
+                   idle_gaps=named, device_ops=ops[:top],
+                   kernel_events=kev, n_gaps=len(idle))

@@ -131,9 +131,16 @@ def run(cell, args, device, t_start, H, system, jax) -> int:
     out = {"correct": False, "attempted": attempted, "failed": failed}
     device = dict(device, memory_peak_bytes=mem_peak)
     if trace:
-        summary = devtrace.summarize(devtrace.read(tdir),
-                                     kernels=("streaming_topk",))
+        t = time.monotonic()
+        raw = devtrace.read(tdir)
+        t_read = time.monotonic() - t
+        t = time.monotonic()
+        summary = devtrace.summarize(raw, kernels=("streaming_topk",))
+        t_sum = time.monotonic() - t
         H.remove(tdir)
+        H.log(f"[trace] read {t_read:.3f} s, summarize {t_sum:.3f} s; "
+              f"{sum(map(len, raw.device_ops.values()))} device operations, "
+              f"{len(raw.host_spans)} host spans, {summary.n_gaps} gaps")
         rec = H.RunRecord(window=win, requests=recs, trace=summary,
                           peaks=(device_peaks(device["kind"])
                                  if not args.rehearse else None))

@@ -2,7 +2,9 @@
 events and shapes (no device needed)."""
 import importlib.util
 import json
+import random
 import sys
+import time
 from pathlib import Path
 
 import pytest
@@ -66,6 +68,132 @@ def test_a_trace_without_its_window_or_device_ops_is_refused():
         devtrace.summarize(devtrace.Trace({"/device:TPU:0": []}, []))
     with pytest.raises(ValueError):
         devtrace.summarize(devtrace.Trace({}, [E("bench.window", 0, 9)]))
+
+
+def _summarize_naming_every_gap(trace, kernels=(), top=10):
+    """The reduction as it was before only the longest gaps were named:
+    every gap named, then sorted and cut to ``top``."""
+    lo, hi = devtrace.window_of(trace)
+    busy = [devtrace.busy_ns(evs, lo, hi)
+            for evs in trace.device_ops.values()]
+    evs = trace.device_ops[sorted(trace.device_ops)[0]]
+    idle = sorted(((devtrace.name_gap(g, trace.host_spans),
+                    (g[1] - g[0]) * 1e-9)
+                   for g in devtrace.gaps(evs, lo, hi)), key=lambda x: -x[1])
+    per_op: dict = {}
+    for dev in trace.device_ops.values():
+        for ev in dev:
+            d = devtrace._overlap(lo, hi, ev.start_ns, ev.end_ns)
+            if d > 0:
+                key = devtrace.op_name(ev.name)
+                per_op[key] = per_op.get(key, 0.0) + d * 1e-9
+    n_dev = len(trace.device_ops)
+    ops = sorted(((k, v / n_dev) for k, v in per_op.items()),
+                 key=lambda x: -x[1])
+    kev = {k: [ev for dev in trace.device_ops.values() for ev in dev
+               if k in ev.name and lo <= ev.start_ns and ev.end_ns <= hi]
+           for k in kernels}
+    return ((hi - lo) * 1e-9, sum(busy) / n_dev * 1e-9, idle[:top],
+            ops[:top], kev)
+
+
+def _random_trace(seed: int):
+    """Ops and spans on a 10 ns grid, so that many gaps share a length
+    and many spans share an overlap and a length; spans nest, some only
+    wait, and some are copies of another under a different name."""
+    rng = random.Random(seed)
+    lo, hi = 1000, 1000 + 10 * rng.randrange(150, 400)
+    names = ["fusion.3", "fusion.4", "sort.1", "vmap_streaming_topk_.1",
+             TOPK_EVENT]
+    dev = {}
+    for d in range(rng.choice([1, 1, 2])):
+        dev[f"/device:TPU:{d}"] = [
+            E(rng.choice(names), lo + 10 * rng.randrange(-5, 420),
+              10 * rng.randrange(1, 4)) for _ in range(rng.randrange(20, 90))]
+    host = [E("bench.window", lo, hi - lo)]
+    for _ in range(rng.randrange(1, 4)):
+        host.append(E("bench.sleep", lo + 10 * rng.randrange(-5, 300),
+                      10 * rng.randrange(20, 200)))
+    working = ("bench.submit", "bench.batch_wait", "bench.result_wait",
+               "bench.execute_batch")
+    for _ in range(rng.randrange(10, 60)):
+        outer = E(rng.choice(working), lo + 10 * rng.randrange(-5, 400),
+                  10 * rng.randrange(1, 30))
+        host.append(outer)
+        if rng.random() < 0.4:        # nested inside it
+            host.append(E("bench.dispatch", outer.start_ns + 10,
+                          max(10, outer.dur_ns - 20)))
+        if rng.random() < 0.3:        # equal in overlap and length
+            host.append(E(rng.choice(working), outer.start_ns,
+                          outer.dur_ns))
+    rng.shuffle(host)
+    return devtrace.Trace(dev, host)
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_naming_only_the_longest_gaps_changes_nothing(seed):
+    t = _random_trace(seed)
+    for top in (1, 3, 10, 10_000):
+        s = devtrace.summarize(t, kernels=("streaming_topk", "sort"),
+                               top=top)
+        want = _summarize_naming_every_gap(
+            t, kernels=("streaming_topk", "sort"), top=top)
+        assert (s.window_s, s.busy_s, s.idle_gaps, s.device_ops,
+                s.kernel_events) == want
+        assert s.n_gaps == len(devtrace.gaps(
+            t.device_ops[sorted(t.device_ops)[0]], *devtrace.window_of(t)))
+
+
+def test_the_random_traces_hold_ties():
+    # the case the stable sort has to keep: gaps of one length, and ties
+    # among them at the cut
+    tied = at_cut = 0
+    for seed in range(8):
+        s = devtrace.summarize(_random_trace(seed), top=10_000)
+        lengths = [x for _, x in s.idle_gaps]
+        tied += len(lengths) - len(set(lengths))
+        at_cut += sum(lengths[top - 1:top + 1].count(lengths[top - 1]) == 2
+                      for top in (1, 3, 10) if top < len(lengths))
+    assert tied >= 20 and at_cut >= 3
+
+
+def test_summarize_names_at_most_top_gaps(monkeypatch):
+    calls = []
+    name_gap = devtrace.name_gap
+    monkeypatch.setattr(devtrace, "name_gap",
+                        lambda g, spans: calls.append(g) or name_gap(g, spans))
+    t = _random_trace(3)
+    s = devtrace.summarize(t, top=4)
+    assert s.n_gaps > 4 and len(calls) == 4
+    assert [round(x * 1e9) for _, x in s.idle_gaps] == \
+        [round(g1 - g0) for g0, g1 in calls]
+
+
+def test_a_fast_servers_window_reduces_in_seconds():
+    # one 51 s window at about 490 q/s: 25,000 submits, 800 rung-32
+    # batches of four spans each and 100 device operations with a gap
+    # after each; naming every gap would pair 2.3e9 gaps and spans
+    lo, hi = 0, 51e9
+    host = [E("bench.window", lo, hi - lo)]
+    host += [E("bench.submit", i * 2.04e6, 0.05e6) for i in range(25_000)]
+    ops, batch = [], (hi - lo) / 800
+    for b in range(800):
+        t0 = lo + b * batch
+        host += [E("bench.batch_wait", t0, 0.2e6),
+                 E("bench.execute_batch", t0 + 0.2e6, batch - 0.4e6),
+                 E("bench.dispatch", t0 + 0.3e6, 1e6),
+                 E("bench.result_wait", t0 + 1.3e6, batch - 1.6e6)]
+        step = (batch - 0.4e6) / 100
+        ops += [E(f"fusion.{k}", t0 + 0.2e6 + k * step, step - 3e3 - 7 * k)
+                for k in range(100)]
+    t = devtrace.Trace({"/device:TPU:0": ops}, host)
+    t0 = time.monotonic()
+    s = devtrace.summarize(t, kernels=("streaming_topk",))
+    took = time.monotonic() - t0
+    assert s.n_gaps == 80_000 + 1 and len(s.idle_gaps) == 10
+    # the 799 gaps between batches tie at 403,693 ns; the first ten win
+    assert s.idle_gaps == [("batch_wait", pytest.approx(403_693e-9))] * 10
+    assert took < 20, f"summarize took {took:.1f} s"
 
 
 @pytest.mark.parametrize("rows", [8, 16, 32])
