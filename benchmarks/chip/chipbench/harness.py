@@ -155,14 +155,20 @@ def served_requests(win: loadgen.Window) -> tuple:
             failed += 1
             continue
         tr = req.trace
-        recs.append({"row": i, "due": due, "t_done": tr.t_done,
-                     "latency_ms": 1000.0 * (tr.t_done - due),
-                     "queue_wait_ms": tr.queue_wait_ms,
-                     "service_ms": tr.service_ms,
-                     "batch_size": tr.batch_size, "bucket": tr.bucket,
-                     "stage_ms": tr.stage_ms,
-                     "docids": np.asarray(req.result["docids"])[0],
-                     "scores": np.asarray(req.result["scores"])[0]})
+        rec = {"row": i, "due": due, "t_done": tr.t_done,
+               "latency_ms": 1000.0 * (tr.t_done - due),
+               "queue_wait_ms": tr.queue_wait_ms,
+               "service_ms": tr.service_ms,
+               "batch_size": tr.batch_size, "bucket": tr.bucket,
+               "stage_ms": tr.stage_ms,
+               "docids": np.asarray(req.result["docids"])[0],
+               "scores": np.asarray(req.result["scores"])[0]}
+        if "tokens" in req.result:
+            # ttft_ms and n_tokens stay 0 for an answer the stage cache
+            # served whole: it decoded nothing
+            rec.update(tokens=np.asarray(req.result["tokens"])[0],
+                       ttft_ms=tr.ttft_ms, n_tokens=tr.n_tokens)
+        recs.append(rec)
     return recs, len(win.sent), failed
 
 
@@ -201,9 +207,13 @@ def answered(recs: list, tr: Traffic) -> tuple:
 
 
 def check(cell: spec.Cell, coll: datagen.Collection, queries: dict,
-          answered: list, seed: int, ref_dtype=np.float64) -> dict:
+          answered: list, seed: int, ref_dtype=np.float64,
+          lm: dict | None = None) -> dict:
     """Reference rankings of a sample of the answers, and the numbers
-    compared.  ``queries`` holds one row per answer, in order."""
+    compared.  ``queries`` holds one row per answer, in order.  A
+    reference module that defines ``numbers`` adds what it returns (a
+    generating chain's ``logit_gap``); ``lm`` is the generating leaf's
+    LM as ``system.lm_spec`` gives it."""
     ref_spec = cell.config["reference"]
     ref = spec.reference_module(ref_spec["name"])
     idx = correctness.sample(len(answered), int(cell.traffic["check_sample"]),
@@ -215,10 +225,14 @@ def check(cell: spec.Cell, coll: datagen.Collection, queries: dict,
     refs = ref.run(rcoll, Qs, ref_spec, seed=seed, dtype=ref_dtype)
     sample = [answered[i] for i in idx]
     k = int(ref_spec["k"])
-    return {"score_gap": correctness.score_gap(sample, refs, k, coll.n_docs),
+    nums = {"score_gap": correctness.score_gap(sample, refs, k, coll.n_docs),
             "abs_score_gap": correctness.score_gap(sample, refs, k,
                                                    coll.n_docs, divide=False),
             "n_checked": len(idx)}
+    if hasattr(ref, "numbers"):
+        nums.update(ref.numbers(rcoll, Qs, sample, refs, ref_spec,
+                                seed=seed, lm=lm))
+    return nums
 
 
 def trace_dir() -> str:

@@ -7,13 +7,36 @@ per-layer metric is a file of its own under ``benchmarks/chip``:
     configs/<config>.json     sizes, pipeline, reference (data)
     traffic/<traffic>.json    arrivals, query shape, serving policy (data)
     checks/<cell>.json        the limit of each number compared (data)
-    metrics/<metric>.py       a reader: ``read(run) -> float | None``
+    metrics/<metric>.py       a reader: ``read(run) -> float | None``, and
+                              ``KERNELS``, the kernels a traced run times
+    references/<r>.py         a plain reference: ``run`` ranks, and
+                              ``numbers``, where defined, adds numbers
 
 so a later cell, configuration or metric is added by adding files.
+
+A cell whose pipeline ends in ``Generate`` adds:
+
+* ``configs/<c>.json`` with an ``lm`` section: ``name`` (the model the
+  pipeline's ``Generate`` names), ``arch`` (an id of
+  ``repro.configs.registry``), ``overrides`` (applied to the arch's
+  published config, a dict nesting into a dataclass field such as
+  ``moe``) and ``rehearse`` (applied to its ``reduced()`` config under
+  ``--rehearse``).  ``system.build`` draws the weights by
+  ``chipbench/weights.py``'s rule and registers the LM before the server
+  is built;
+* ``references/<r>.py`` with ``numbers``, which holds the served tokens
+  to a plain decoder (``references/generate.py``: ``logit_gap``), and
+  with ``control``, the same reference in a lower precision, for
+  ``control.py``;
+* ``checks/<cell>.json`` naming any number the reference returns
+  (``logit_gap``); a name no number matches ends the run with exit 1;
+* ``metrics/<m>.py`` with ``KERNELS`` naming the kernels it reads from the
+  trace.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -76,8 +99,8 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                            if _reports(m, name)])
 
 
-def metric_reader(name: str):
-    """The ``read`` function of ``metrics/<name>.py``."""
+@functools.cache
+def _metric_module(name: str):
     path = HERE / "metrics" / f"{name}.py"
     if not path.is_file():
         raise SpecError(f"no reader for per-layer metric {name!r} "
@@ -86,7 +109,22 @@ def metric_reader(name: str):
         f"chipbench_metric_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    """The ``read`` function of ``metrics/<name>.py``."""
+    return _metric_module(name).read
+
+
+def kernels(metrics: list) -> tuple:
+    """The kernels a traced run times: the union, in order, of the
+    ``KERNELS`` that the metrics' readers declare."""
+    out = {}
+    for m in metrics:
+        out.update(dict.fromkeys(getattr(_metric_module(m["name"]),
+                                         "KERNELS", ())))
+    return tuple(out)
 
 
 def reference_module(name: str):

@@ -1,6 +1,6 @@
 """The system under test, built through the entry points a user calls:
-``build_index``, ``JaxBackend``, the pipeline operators and
-``PipelineServer``."""
+``build_index``, ``JaxBackend``, ``register_lm``, the pipeline operators
+and ``PipelineServer``."""
 from __future__ import annotations
 
 import dataclasses
@@ -9,13 +9,14 @@ import time
 
 import jax
 
-from chipbench import datagen
+from chipbench import datagen, weights
 from chipbench.spec import ROOT
 
 sys.path.insert(0, str(ROOT / "src"))
 
 from repro import core  # noqa: E402
 from repro.core import BackendDescriptor, JaxBackend  # noqa: E402
+from repro.configs.registry import get_arch  # noqa: E402
 from repro.core.descriptor import DEFAULT_CAPABILITIES  # noqa: E402
 from repro.index import build_index  # noqa: E402
 from repro.index.corpus import Corpus  # noqa: E402
@@ -41,6 +42,56 @@ def pipeline(config: dict):
     return out
 
 
+def _replace(obj, overrides: dict):
+    """``obj`` with ``overrides`` applied by ``dataclasses.replace``; a
+    dict given for a dataclass field nests into it, a string for a type
+    field (``dtype``) names a NumPy dtype."""
+    kw = {}
+    for k, v in overrides.items():
+        cur = getattr(obj, k)
+        if isinstance(v, dict) and dataclasses.is_dataclass(cur):
+            v = _replace(cur, v)
+        elif isinstance(v, str) and isinstance(cur, type):
+            v = jax.numpy.dtype(v).type
+        kw[k] = v
+    return dataclasses.replace(obj, **kw)
+
+
+def lm_config(config: dict, rehearse: bool):
+    """The configuration's LM: ``lm.arch``'s published config with
+    ``lm.overrides``, or under ``rehearse`` its ``reduced()`` config with
+    ``lm.rehearse``; None for a configuration without ``lm``."""
+    lm = config.get("lm")
+    if lm is None:
+        return None
+    arch = get_arch(lm["arch"])
+    if rehearse:
+        return _replace(arch.reduced()[0], lm.get("rehearse", {}))
+    return _replace(arch.model_cfg(), lm.get("overrides", {}))
+
+
+def lm_spec(cfg) -> dict:
+    """The LM's sizes as plain numbers, for a reference: every field of
+    its config, the dtype by name, a nested config as a dict."""
+    out = {}
+    for f in dataclasses.fields(cfg):
+        v = getattr(cfg, f.name)
+        if dataclasses.is_dataclass(v):
+            v = lm_spec(v)
+        elif isinstance(v, type):
+            v = jax.numpy.dtype(v).name
+        out[f.name] = v
+    return out
+
+
+def lm_weights(config: dict, cfg, seed: int):
+    """The LM's weights by ``chipbench/weights.py``'s rule, over the tree
+    of the program's parameters."""
+    init = get_arch(config["lm"]["arch"]).module.init_params
+    shapes = jax.eval_shape(lambda: init(cfg, jax.random.key(0)))
+    return weights.draw(shapes, seed)
+
+
 @dataclasses.dataclass
 class System:
     coll: datagen.Collection
@@ -49,6 +100,8 @@ class System:
     compile_report: dict | None = None
     #: seconds of each set-up step, in order
     timings: dict = dataclasses.field(default_factory=dict)
+    #: the generating leaf's LM (``lm_spec``), None without one
+    lm: dict | None = None
 
     def close(self) -> None:
         if self.server is not None:
@@ -57,8 +110,9 @@ class System:
 
 def build(config: dict, traffic: dict, seed: int, *, rehearse: bool,
           stage_timing: bool = False) -> System:
-    """Collection, index, backend and the cell's one compiled pipeline.
-    Warm-up is the caller's (it needs the cell's own queries)."""
+    """Collection, index, backend, the configuration's LM where it has
+    one, and the cell's one compiled pipeline.  Warm-up is the caller's
+    (it needs the cell's own queries)."""
     t, times = time.monotonic(), {}
 
     def step(name):
@@ -79,8 +133,15 @@ def build(config: dict, traffic: dict, seed: int, *, rehearse: bool,
                          seed=seed)
     jax.block_until_ready(backend.dense.emb)
     step("backend")
-    pipe = pipeline(config)
     sys_ = System(coll, backend, timings=times)
+    cfg_lm = lm_config(config, rehearse)
+    if cfg_lm is not None:
+        params = lm_weights(config, cfg_lm, seed)
+        jax.block_until_ready(params)
+        backend.register_lm(config["lm"]["name"], cfg_lm, params=params)
+        sys_.lm = lm_spec(cfg_lm)
+        step("lm")
+    pipe = pipeline(config)
     cfg = ServeConfig.default(**traffic.get("serve", {}))
     if stage_timing:
         cfg = cfg.with_tracing(stages=True)

@@ -8,11 +8,14 @@ reference computed in bfloat16 and put in the program's place, read by the
 same comparison against the float64 reference (every number a run
 compares, and ``abs_score_gap``).  With ``--dense-only`` only the dense
 vectors and their contraction are bfloat16 (for a reference with a dense
-stage).  Each number's smallest reading over the seeds is the upper end
-its limit must stay under; the program's own readings (the lower end) are
-the ``checks`` of the cell's runs.  The benchmark's own runs never run
-this.  Prints one JSON line per seed and writes them to
-``<--out>/control-<cell>.json`` (default ``bench_out/`` in the checkout).
+stage).  A reference that defines ``control`` (a generating chain's) is
+called instead: it answers in its own lower precision, and its answers
+carry the tokens it decodes.  Each number's smallest reading over the
+seeds is the upper end its limit must stay under; the program's own
+readings (the lower end) are the ``checks`` of the cell's runs.  The
+benchmark's own runs never run this.  Prints one JSON line per seed and
+writes them to ``<--out>/control-<cell>.json`` (default ``bench_out/`` in
+the checkout).
 """
 from __future__ import annotations
 
@@ -28,14 +31,20 @@ from chipbench import correctness, datagen, harness as H, spec  # noqa: E402
 
 
 def control_answers(cell, coll, queries: dict, seed: int,
-                    dense_only: bool = False) -> list:
-    """The bfloat16 reference's own top-k, as the program would answer."""
+                    dense_only: bool = False, lm: dict | None = None) -> list:
+    """The bfloat16 reference's own top-k, as the program would answer;
+    or the answers of the reference's own ``control``."""
     import ml_dtypes
     ref_spec = cell.config["reference"]
     ref = spec.reference_module(ref_spec["name"])
     rcoll = ref.Collection(coll.doc_terms, coll.doc_start, coll.vocab,
                            float(cell.config["collection"]
                                  ["stop_df_fraction"]))
+    if hasattr(ref, "control"):
+        if dense_only:
+            raise SystemExit(f"reference {ref_spec['name']!r} has a control "
+                             f"of its own; --dense-only does not apply")
+        return ref.control(rcoll, queries, ref_spec, seed=seed, lm=lm)
     if dense_only:
         low = ref.run(rcoll, queries, ref_spec, seed=seed,
                       dense_dtype=ml_dtypes.bfloat16)
@@ -56,13 +65,15 @@ def reading(cell, seed: int, seconds: float, rehearse: bool,
     idx = correctness.sample(len(queries["qid"]),
                              int(cell.traffic["check_sample"]), seed)
     sample = {k: v[idx] for k, v in queries.items()}
-    low = control_answers(cell, coll, sample, seed, dense_only)
+    cfg_lm = system.lm_config(cell.config, rehearse)
+    lm = None if cfg_lm is None else system.lm_spec(cfg_lm)
+    low = control_answers(cell, coll, sample, seed, dense_only, lm)
     # the comparison a run makes, with the control's answers in place of
     # the program's: the same sample of the same queries
     answers = [None] * len(queries["qid"])
     for j, i in enumerate(idx):
         answers[i] = low[j]
-    nums = H.check(cell, coll, queries, answers, seed)
+    nums = H.check(cell, coll, queries, answers, seed, lm=lm)
     return {"seed": seed, **{n: nums[n] for n in cell.checks},
             "abs_score_gap": nums["abs_score_gap"],
             "seconds": time.monotonic() - t}

@@ -6,6 +6,8 @@ Nothing when the kernel did not run (for instance when the fusion gate
 kept the unfused chain)."""
 from chipbench import kernel_cost
 
+KERNELS = ("streaming_topk",)
+
 
 def read(run):
     if run.trace is None or run.peaks is None:

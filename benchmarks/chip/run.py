@@ -5,10 +5,12 @@
 
 Set-up (``setup_s``, from process start to the window's start) makes the
 cell's collection on the device from the seed, builds the program's index
-and backend, compiles the cell's one pipeline and warms up every shape its
-traffic uses.  Then the window is driven for ``--seconds``, answers are
-awaited, the program is freed, and a sample of the answers is compared
-with the configuration's plain reference.
+and backend, draws and registers the configuration's LM where it has one,
+compiles the cell's one pipeline and warms up every shape its traffic
+uses.  Then the window is driven for ``--seconds``, answers are awaited,
+the program is freed, and a sample of the answers is compared with the
+configuration's plain reference.  A number that ``checks/<cell>.json``
+names and the reference does not give ends the run with exit code 1.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
@@ -90,6 +92,12 @@ def run(cell, args, device, t_start, H, system, jax) -> int:
           + f"; {sys_.coll.n_docs} docs, "
           f"{sys_.coll.doc_terms.size} tokens, max posting list "
           f"{sys_.backend.max_postings}")
+    if sys_.lm is not None:
+        lm = sys_.lm
+        H.log(f"[setup] lm {lm['name']}: {lm['n_layers']} layers, d_model "
+              f"{lm['d_model']}, GQA {lm['n_q']}/{lm['n_kv']} x "
+              f"{lm['d_head']}, d_ff {lm['d_ff']}, vocab {lm['vocab']}, "
+              f"{lm['dtype']}")
     H.log(f"[setup] compiled chain {system.compiled_chain(sys_)}; gate "
           f"{system.gate_decisions(sys_)}")
     tr = H.make_traffic(cell.traffic, seconds, args.seed,
@@ -126,6 +134,15 @@ def run(cell, args, device, t_start, H, system, jax) -> int:
           f"p95 {stats.percentile(late, 95):.6f} s, max {max(late):.6f} s")
     H.log(f"[window] compiles inside the window: engine_compiles_total "
           f"+{engine_in_window}, XLA backend compiles +{xla_in_window}")
+    gen = [r for r in recs if "tokens" in r]
+    if gen:
+        # an answer the stage cache served whole decoded nothing and has
+        # no time to its first token
+        ttft = [r["ttft_ms"] for r in gen if r["n_tokens"]]
+        p50 = (f"{stats.percentile(ttft, 50):.3f}" if ttft else "none")
+        H.log(f"[window] generated answers {len(gen)}, {len(ttft)} "
+              f"decoded: ttft_ms p50 {p50}; tokens "
+              f"{sum(r['n_tokens'] for r in gen)}")
     queries, answers = H.answered(recs, tr)
 
     out = {"correct": False, "attempted": attempted, "failed": failed}
@@ -135,7 +152,8 @@ def run(cell, args, device, t_start, H, system, jax) -> int:
         raw = devtrace.read(tdir)
         t_read = time.monotonic() - t
         t = time.monotonic()
-        summary = devtrace.summarize(raw, kernels=("streaming_topk",))
+        summary = devtrace.summarize(raw,
+                                     kernels=spec.kernels(cell.per_layer))
         t_sum = time.monotonic() - t
         H.remove(tdir)
         H.log(f"[trace] read {t_read:.3f} s, summarize {t_sum:.3f} s; "
@@ -153,14 +171,19 @@ def run(cell, args, device, t_start, H, system, jax) -> int:
         out["metrics"] = H.end_to_end(cell, win, recs, setup_s)
         out["device"] = device
 
-    coll = sys_.coll
+    coll, lm = sys_.coll, sys_.lm
     H.free(sys_)
     del sys_
     t = time.monotonic()
-    nums = H.check(cell, coll, queries, answers, args.seed)
+    nums = H.check(cell, coll, queries, answers, args.seed, lm=lm)
     H.log(f"[check] reference over {nums['n_checked']} answers in "
           f"{time.monotonic() - t:.3f} s; abs_score_gap "
           f"{nums['abs_score_gap']!r} (not compared)")
+    missing = [name for name in cell.checks if name not in nums]
+    if missing:
+        H.eprint(f"error: checks/{cell.name}.json names {missing}, which "
+                 f"the reference does not give; it gives {sorted(nums)}")
+        return 1
     checks = {name: {"value": min(nums[name], sys.float_info.max),
                      "limit": float(lim["limit"])}
               for name, lim in cell.checks.items()}
